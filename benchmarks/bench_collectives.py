@@ -2,8 +2,10 @@
 
 (a) the alpha-beta cost model across p and message size (ring vs tree/PS vs
 hierarchical vs 2D-mesh — Tables/figures 10-12's shapes), (b) MEASURED
-wall times of our ppermute implementations on an 8-device host mesh, run in
-a subprocess so this process keeps its 1-device view, and (c) the
+wall times of our ppermute implementations on an 8-device CPU host mesh,
+run in a ``JAX_PLATFORMS=cpu`` subprocess so this process keeps its own
+devices (rows ``fig10/measured_8dev_cpu/*``: CPU times, not device
+metrics), and (c) the
 PER-BUCKET {compress, permute, decompress} breakdown of the fused
 compressed wires (DESIGN.md §11) — fused one-pass kernels vs the
 decomposed op chain, per wire × bucket size.
@@ -30,7 +32,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import time
 import jax, jax.numpy as jnp
-import repro.compat  # AxisType/shard_map shims on old JAX
 from jax.sharding import PartitionSpec as P, AxisType
 from repro.core.collectives import allreduce
 
@@ -159,16 +160,22 @@ def run():
                 emit(f"fig10/{algo}/p{p}/{tag}", t * 1e6,
                      f"alpha-beta model")
     fused_wire_breakdown()
-    env = dict(os.environ)
+    # 8 fake CPU devices in a child: this process may hold the chip, and a
+    # child that reached for it would fail or hang
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     res = subprocess.run([sys.executable, "-c", MEASURE_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600)
+    if res.returncode:
+        raise RuntimeError(f"8-device CPU measurement failed "
+                           f"(rc {res.returncode}):\n{res.stderr[-2000:]}")
     for line in res.stdout.splitlines():
         if line.startswith("MEASURED,"):
             _, algo, us = line.split(",")
             what = ("int8+scales payload permute" if algo ==
                     "gather_int8_payload" else "4MiB allreduce")
-            emit(f"fig10/measured_8dev/{algo}", float(us), what)
+            emit(f"fig10/measured_8dev_cpu/{algo}", float(us),
+                 f"{what}, 8 host CPU devices")
 
 
 def main(argv=None) -> int:

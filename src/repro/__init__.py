@@ -1,3 +1,1 @@
-"""Survey reproduction package.  Importing any ``repro.*`` module installs
-the JAX version-compat shims first (see ``repro.compat``)."""
-from repro import compat  # noqa: F401
+"""Survey reproduction package."""

@@ -41,6 +41,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import load_arrays as load_ckpt_arrays
 from repro.checkpoint import save as save_ckpt
@@ -659,9 +660,16 @@ class TrainSession:
         self._anchor = None
         self._red_state = None
         if self.strategy is None:
+            # params/optimizer state replicated over the session mesh, the
+            # batch split over its data axes: XLA inserts the gradient
+            # all-reduce (without shardings everything ran on device 0)
+            rep = NamedSharding(self.mesh, P())
+            self._params = self._place(self._params, False)
+            self._opt_state = self._place(self._opt_state, False)
             self._base = jax.jit(
                 make_train_step(self.model, self.optimizer),
-                donate_argnums=(0, 1))
+                in_shardings=(rep, rep, self._batch_sharding(), rep),
+                out_shardings=(rep, rep, rep), donate_argnums=(0, 1))
             self._built = True
             return
 
@@ -720,7 +728,19 @@ class TrainSession:
             self._params = broadcast_worker_state(self._params, self.world)
             self._opt_state = broadcast_worker_state(self._opt_state,
                                                      self.world)
+        self._params = self._place(self._params, sched.diverges_params)
+        self._opt_state = self._place(self._opt_state, sched.diverges_params)
+        self._sync_state = self._place(self._sync_state, True)
         self._built = True
+
+    def _place(self, tree, per_worker: bool):
+        """Commit state to the session mesh in the layout the step programs
+        return it in: whole on every device, or split over the data axes
+        along a leading per-worker axis.  Left uncommitted, the first step
+        compiles once for it and the second compiles again for the step's
+        own outputs."""
+        spec = P(tuple(self.axes)) if per_worker else P()
+        return jax.device_put(tree, NamedSharding(self.mesh, spec))
 
     def _build_pipeline(self, st: SyncStrategy) -> None:
         """Pipeline-parallel programs (DESIGN.md §9): rebuild the mesh as
@@ -836,6 +856,9 @@ class TrainSession:
         else:
             self._opt_state = init_opt_rows(self._params)  # replaces replicated
         self._sync_state = init_sync_state(self._params)
+        self._params = self._place(self._params, False)
+        self._opt_state = self._place(self._opt_state, True)
+        self._sync_state = self._place(self._sync_state, True)
         self._anchor = None
         self._red_state = None
 
@@ -860,11 +883,18 @@ class TrainSession:
 
     # -- stepping ------------------------------------------------------------
 
+    def _batch_sharding(self) -> NamedSharding:
+        """Global batch split over the data axes on its leading dim (whole
+        on every device when it does not divide)."""
+        split = self.cfg.batch % self.world == 0
+        return NamedSharding(self.mesh,
+                             P(tuple(self.axes)) if split else P())
+
     def step_once(self) -> float:
         """Run one training step under the strategy; returns the loss."""
         self._build()
         step = self.step
-        batch = jax.tree.map(jnp.asarray, self.data.batch(step))
+        batch = jax.device_put(self.data.batch(step), self._batch_sharding())
         step_i = jnp.asarray(step, jnp.int32)
         rng_s = jax.random.fold_in(self.rng, step)
 

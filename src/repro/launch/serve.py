@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ALL_ARCHS, get_config, reduced
+from repro.launch.paths import use_compile_cache
 from repro.models import Model
 
 
@@ -157,6 +158,7 @@ def main(argv=None):
     from repro.serve.engine import latency_summary, poisson_trace
 
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -19,6 +19,7 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import GradientSynchronizer, PlanExecutor, SyncConfig
 from repro.core.schedule.planner import CommPlan
 from repro.models import Model
+from repro.models.sharding_ctx import manual_axes
 from repro.optim import apply_updates, make_optimizer
 
 
@@ -153,7 +154,7 @@ def _make_synced_train_step(model: Model, optimizer, synchronizer, mesh,
         if per_worker_params:
             params = jax.tree.map(lambda s: s[0], params)
             opt_state = jax.tree.map(lambda s: s[0], opt_state)
-        with manual_region(data_axes):
+        with manual_region():
             loss, grads = jax.value_and_grad(model.loss)(params, batch)
         grads, sync_state = synchronizer(grads, sync_state, rng)
         updates, opt_state = optimizer.update(grads, opt_state, params, step)
@@ -179,7 +180,8 @@ def _make_synced_train_step(model: Model, optimizer, synchronizer, mesh,
             body, mesh=mesh,
             in_specs=(p_spec, p_spec, state_spec, batch_spec, P(), P()),
             out_specs=(p_spec, p_spec, state_spec, P(), ),
-            axis_names=set(data_axes), check_vma=False)
+            axis_names=manual_axes(mesh, data_axes),
+            check_vma=False)
         return f(params, opt_state, sync_state, batch, step, rng)
 
     def init_sync_state(params):
@@ -229,7 +231,7 @@ def make_sharded_train_step(model: Model, executor, layout, sharded_opt,
         from repro.models.sharding_ctx import manual_region
         sync_state = jax.tree.map(lambda s: s[0], sync_state)
         opt = jax.tree.map(lambda s: s[0], opt_rows)
-        with manual_region(data_axes):
+        with manual_region():
             loss, grads = jax.value_and_grad(model.loss)(params, batch)
         gshards, sync_state = executor.sync_shards(grads, sync_state, rng)
         updates, inner = sharded_opt.update(gshards, opt["opt"],
@@ -264,7 +266,8 @@ def make_sharded_train_step(model: Model, executor, layout, sharded_opt,
             body, mesh=mesh,
             in_specs=(P(), state_spec, state_spec, batch_spec, P(), P()),
             out_specs=(P(), state_spec, state_spec, P()),
-            axis_names=set(data_axes), check_vma=False)
+            axis_names=manual_axes(mesh, data_axes),
+            check_vma=False)
         return f(params, opt_rows, sync_state, batch, step, rng)
 
     def init_opt_rows(params):
@@ -384,7 +387,7 @@ def make_pipeline_train_step(staged, optimizer, engine, mesh,
 
     def body(params, opt_state, sync_state, batch, step, rng):
         from repro.models.sharding_ctx import manual_region
-        with manual_region((pipe_axis,) + axes):
+        with manual_region():
             return _body(params, opt_state, sync_state, batch, step, rng)
 
     def _body(params, opt_state, sync_state, batch, step, rng):
@@ -536,7 +539,8 @@ def make_pipeline_train_step(staged, optimizer, engine, mesh,
             in_specs=(params_spec, opt_spec, state_spec, batch_spec, P(),
                       P()),
             out_specs=(params_spec, opt_spec, state_spec, P()),
-            axis_names={pipe_axis} | set(axes), check_vma=False)
+            axis_names=manual_axes(mesh, (pipe_axis, *axes)),
+            check_vma=False)
         return f(params, opt_state, sync_state, batch, step, rng)
 
     def init_opt_state(split_params):
@@ -582,7 +586,7 @@ def make_local_train_step(model: Model, optimizer, mesh,
         from repro.models.sharding_ctx import manual_region
         params = jax.tree.map(lambda s: s[0], params)
         opt_state = jax.tree.map(lambda s: s[0], opt_state)
-        with manual_region(data_axes):
+        with manual_region():
             loss, grads = jax.value_and_grad(model.loss)(params, batch)
         updates, opt_state = optimizer.update(grads, opt_state, params, step)
         params = apply_updates(params, updates)
@@ -596,7 +600,8 @@ def make_local_train_step(model: Model, optimizer, mesh,
             body, mesh=mesh,
             in_specs=(state_spec, state_spec, batch_spec, P()),
             out_specs=(state_spec, state_spec, P()),
-            axis_names=set(data_axes), check_vma=False)
+            axis_names=manual_axes(mesh, data_axes),
+            check_vma=False)
         return f(params, opt_state, batch, step)
 
     return step_fn
@@ -630,7 +635,8 @@ def make_param_round_step(reducer, mesh, data_axes: Sequence[str] = ("data",),
         def round_fn(params, anchor, red_state, rng):
             f = jax.shard_map(avg_body, mesh=mesh, in_specs=(state_spec,),
                               out_specs=state_spec,
-                              axis_names=set(data_axes), check_vma=False)
+                              axis_names=manual_axes(mesh, data_axes),
+                              check_vma=False)
             return f(params), anchor, red_state
 
         return round_fn
@@ -657,7 +663,8 @@ def make_param_round_step(reducer, mesh, data_axes: Sequence[str] = ("data",),
             body, mesh=mesh,
             in_specs=(state_spec, P(), state_spec, P()),
             out_specs=(state_spec, P(), state_spec),
-            axis_names=set(data_axes), check_vma=False)
+            axis_names=manual_axes(mesh, data_axes),
+            check_vma=False)
         return f(params, anchor, red_state, rng)
 
     return round_fn
@@ -683,7 +690,7 @@ def make_lag_programs(model: Model, optimizer, synchronizer, mesh,
 
     def probe_body(params, batch, g_last):
         from repro.models.sharding_ctx import manual_region
-        with manual_region(data_axes):
+        with manual_region():
             loss, grads = jax.value_and_grad(model.loss)(params, batch)
 
         def sq(t):
@@ -702,7 +709,8 @@ def make_lag_programs(model: Model, optimizer, synchronizer, mesh,
             probe_body, mesh=mesh,
             in_specs=(P(), batch_spec, P()),
             out_specs=(P(), state_spec, P(), P()),
-            axis_names=set(data_axes), check_vma=False)
+            axis_names=manual_axes(mesh, data_axes),
+            check_vma=False)
         return f(params, batch, g_last)
 
     def sync_body(params, opt_state, sync_state, grads_w, step, rng):
@@ -719,7 +727,8 @@ def make_lag_programs(model: Model, optimizer, synchronizer, mesh,
             sync_body, mesh=mesh,
             in_specs=(P(), P(), state_spec, state_spec, P(), P()),
             out_specs=(P(), P(), state_spec, P()),
-            axis_names=set(data_axes), check_vma=False)
+            axis_names=manual_axes(mesh, data_axes),
+            check_vma=False)
         return f(params, opt_state, sync_state, grads_w, step, rng)
 
     def reuse_apply(params, opt_state, g_last, step):

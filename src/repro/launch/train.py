@@ -36,6 +36,7 @@ from repro.configs import ALL_ARCHS
 from repro.core import (ParallelismSpec, SyncConfig, SyncStrategy,
                         get_scheduler, make_strategy)
 from repro.core.schedule import LINK_PRESETS
+from repro.launch.paths import use_compile_cache
 from repro.launch.report import render_strategy_plan, save_strategy_plan
 
 
@@ -292,11 +293,20 @@ def run_elastic(args, scfg):
           f"steps {rt.session.step}, comm rounds {rt.comm_rounds} "
           f"(grad {rt.grad_rounds}, param {rt.param_rounds}), "
           f"{len(rt.events)} elastic events")
-    return losses
+    return rt.session, losses
 
 
 def main(argv=None):
+    """The training CLI; returns the run's losses."""
+    return run(argv)[1]
+
+
+def run(argv=None):
+    """The whole CLI path: parse ``argv``, build the session and strategy,
+    train, print the report.  Returns ``(session, losses)`` — under
+    ``--elastic`` the runtime's current session."""
     args = parse_args(argv)
+    use_compile_cache()
     scfg = SessionConfig(
         arch=args.arch, reduced=args.reduced, steps=args.steps,
         batch=args.batch, seq=args.seq, lr=args.lr, warmup=args.warmup,
@@ -461,7 +471,7 @@ def main(argv=None):
         print("checkpoint written:", args.checkpoint)
     print(f"final loss {losses[-1]:.4f} (first {losses[0]:.4f}) "
           f"steps/s {args.steps / session.wall_s:.2f} | {session.summary()}")
-    return losses
+    return session, losses
 
 
 if __name__ == "__main__":

@@ -149,13 +149,7 @@ def moe_ffn(params, cfg: ModelConfig, x, *,
     keep = flat_slot < cap
     dest = jnp.where(keep, eg * cap + flat_slot, E * cap)         # (G, ng*k)
     if _DROP_TAP["enabled"]:
-        # host callbacks abort XLA inside a PARTIAL-manual shard_map body
-        # (manual data axes + a live auto model axis); skip the tap there
-        # rather than crash — counts then read 0 and the summary stays
-        # silent for that (programmatic, model>1) configuration
-        from repro.models.sharding_ctx import host_callback_safe
-        if host_callback_safe():
-            jax.debug.callback(_drop_tap_cb, (~keep).sum(), float(keep.size))
+        jax.debug.callback(_drop_tap_cb, (~keep).sum(), float(keep.size))
 
     tok_idx = jnp.repeat(jnp.arange(ng), k)
     xg = constrain(xf.reshape(G, ng, d), ("b", None, None))

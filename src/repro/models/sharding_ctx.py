@@ -23,7 +23,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 _CTX = {"mesh": None, "batch_axes": None, "model_axis": None, "manual": False,
-        "manual_axes": (), "tp_axis": None}
+        "tp_axis": None}
 
 
 @contextlib.contextmanager
@@ -49,34 +49,25 @@ def tp_axis() -> Optional[str]:
 
 
 @contextlib.contextmanager
-def manual_region(axes: Sequence[str] = ()):
+def manual_region():
     """Inside a shard_map whose manual axes include the data axes, sharding
-    constraints must not name them (and WSC on auto axes under shard_map is
-    buggy in this JAX) — so all constraints become no-ops while tracing the
-    manual body.  ``axes`` names the shard_map's manual axes so
-    :func:`host_callback_safe` can tell full-manual bodies (host callbacks
-    fine) from partial-manual ones (XLA aborts on them — see compat)."""
-    old = _CTX["manual"], _CTX["manual_axes"]
+    constraints must not name them — so all constraints become no-ops
+    while tracing the manual body."""
+    old = _CTX["manual"]
     _CTX["manual"] = True
-    _CTX["manual_axes"] = tuple(axes)
     try:
         yield
     finally:
-        _CTX["manual"], _CTX["manual_axes"] = old
+        _CTX["manual"] = old
 
 
-def host_callback_safe() -> bool:
-    """Whether a host callback (``jax.debug.callback``) may be baked into
-    the program being traced.  False exactly in a PARTIAL-manual shard_map
-    body: manual over some mesh axes while another live (size>1) axis
-    stays auto — XLA's partitioner aborts on the callback custom-call
-    there (hlo_sharding.cc ``!IsManual()``).  Full-manual bodies and
-    ordinary pjit programs are safe."""
-    mesh = _CTX["mesh"]
-    if not _CTX["manual"] or mesh is None:
-        return True
-    manual = set(_CTX["manual_axes"])
-    return all(a in manual or mesh.shape[a] == 1 for a in mesh.axis_names)
+def manual_axes(mesh, axes: Sequence[str]) -> set:
+    """``axis_names`` for a shard_map manual over ``axes``: those axes plus
+    every size-1 axis of ``mesh``.  A one-shard axis computes the same
+    manual or auto, but left auto it makes the body partial-manual, and
+    XLA aborts on a host callback there (the MoE drop tap on the standard
+    ``data(N) x model(1)`` session mesh)."""
+    return set(axes) | {a for a in mesh.axis_names if mesh.shape[a] == 1}
 
 
 def set_mesh_ctx(mesh, batch_axes: Sequence[str], model_axis: Optional[str] = "model"):

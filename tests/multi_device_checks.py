@@ -10,7 +10,6 @@ import sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax  # noqa: E402
-import repro.compat  # noqa: E402,F401  (AxisType/shard_map shims on old JAX)
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P  # noqa: E402
@@ -948,20 +947,17 @@ def check_ep_dp_bit_exact():
 
 def check_drop_tap_shard_map():
     """The MoE drop tap (DESIGN.md §14) must survive the shard_map sync
-    paths: a host callback baked into a PARTIAL-manual body — manual data
-    axes with a size-1 auto model axis left over on the same mesh — made
-    XLA abort outright (hlo_sharding.cc ``!IsManual()``), which is
-    exactly the standard ``data(N) × model(1)`` session mesh every
-    multi-device ``--sync comm`` / ``--parallelism`` run shard_maps over.
-    compat's shard_map now promotes size-1 leftover axes into the manual
-    set (semantically a no-op), so the body is full-manual and the tap
-    FIRES.  (A >1 auto axis remaining is a genuinely-partial-manual body;
-    jax 0.4.37 cannot partition the MoE scatter there at all, tap or no
-    tap — ``moe_ffn`` additionally skips the callback in that case via
-    ``host_callback_safe`` so the tap is never the crashing element.)"""
+    paths.  A host callback baked into a shard_map body manual over the
+    data axes, with a size-1 auto model axis left over on the same mesh,
+    makes XLA abort the process, and ``data(N) × model(1)`` is the
+    standard session mesh every multi-device ``--sync comm`` /
+    ``--parallelism`` run shard_maps over.  The step builders therefore
+    name size-1 axes manual too (``sharding_ctx.manual_axes``, a no-op for
+    the math), so the body is full-manual and the tap FIRES.  A live (>1)
+    auto model axis left over is fine, and the tap fires there too."""
     from repro.configs.base import ModelConfig
     from repro.models import moe
-    from repro.models.sharding_ctx import manual_region, mesh_ctx
+    from repro.models.sharding_ctx import manual_axes, manual_region, mesh_ctx
 
     cfg = ModelConfig(name="t", family="qwen3", num_layers=1, d_model=16,
                       num_heads=2, num_kv_heads=2, d_ff=32, vocab_size=64,
@@ -975,30 +971,31 @@ def check_drop_tap_shard_map():
               "wo": jax.random.normal(ks[3], (E, 24, d)) * 0.3}
     x = jax.random.normal(ks[4], (8, 4, d))
 
-    mesh = jax.make_mesh((8, 1), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
-
     def body(p, xs):
-        with manual_region(("data",)):
+        with manual_region():
             out, _ = moe.moe_ffn(p, cfg, xs)
         return jax.lax.psum(jnp.sum(out ** 2), "data")
 
     old = moe.enable_drop_tap(True)
     try:
-        with mesh_ctx(mesh, ("data",)):
-            f = jax.jit(jax.shard_map(
-                body, mesh=mesh,
-                in_specs=({k: P() for k in params}, P("data")),
-                out_specs=P(), axis_names={"data"}, check_vma=False))
-            moe.drain_drop_tap()
-            float(f(params, x))            # blocks → callbacks have fired
-        dropped, routed = moe.drain_drop_tap()
-        assert routed > 0, (dropped, routed)
-        assert dropped > 0, (dropped, routed)      # cap 0.5 must drop
+        for shape in ((8, 1), (4, 2)):
+            mesh = jax.make_mesh(shape, ("data", "model"),
+                                 axis_types=(AxisType.Auto,) * 2)
+            with mesh_ctx(mesh, ("data",)):
+                f = jax.jit(jax.shard_map(
+                    body, mesh=mesh,
+                    in_specs=({k: P() for k in params}, P("data")),
+                    out_specs=P(), axis_names=manual_axes(mesh, ("data",)),
+                    check_vma=False))
+                moe.drain_drop_tap()
+                float(f(params, x))        # blocks → callbacks have fired
+            dropped, routed = moe.drain_drop_tap()
+            assert routed == x.shape[0] * x.shape[1] * cfg.top_k, routed
+            assert dropped > 0, (shape, dropped, routed)  # cap 0.5 drops
     finally:
         moe.enable_drop_tap(old)
-    print("moe drop tap under shard_map ok (size-1 model axis promoted "
-          "to manual; callback fires on the data(8) x model(1) mesh)")
+    print("moe drop tap under shard_map ok (data(8) x model(1): size-1 "
+          "axis named manual; data(4) x model(2): model axis left auto)")
 
 
 if __name__ == "__main__":

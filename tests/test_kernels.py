@@ -124,8 +124,9 @@ def test_hot_path_is_not_interpreter_off_tpu(monkeypatch):
 # interpret (the Pallas kernel body under the interpreter) and xla (the
 # ref.py lowering) must agree BITWISE under jit — that equivalence is what
 # lets the off-TPU hot path skip the interpreter without changing any
-# payload or residual.  Ragged lengths exercise the pad-and-mask contract.
-@pytest.mark.parametrize("n", [1024, 1000, 2065, 4096])
+# payload or residual.  Ragged lengths exercise the pad-and-mask contract;
+# 301 tiles + 17 also leaves a partial last block of rows in every kernel.
+@pytest.mark.parametrize("n", [1024, 1000, 2065, 4096, 301 * 1024 + 17])
 def test_interpret_matches_xla_bitwise(n):
     g = jax.random.normal(RNG, (n,)) * 2.0
     e = jax.random.normal(jax.random.fold_in(RNG, 1), (n,)) * 0.3

@@ -59,7 +59,6 @@ def test_constrain_divisibility_guard():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
-import repro.compat  # installs AxisType/shard_map shims on old JAX
 from jax.sharding import AxisType
 from repro.models.sharding_ctx import constrain, constrain_hard, mesh_ctx
 mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
