@@ -1,0 +1,8 @@
+"""MoE FFN time per traced step, in ms: device self time, on the busiest
+device, of the ops under the blocks' ``moe`` scope (router, dispatch,
+experts, combine, shared experts), forward and backward (``scopes.py``)."""
+import scopes
+
+
+def read(ctx):
+    return scopes.per_step_ms(ctx, "moe")

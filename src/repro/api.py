@@ -123,6 +123,35 @@ def strategy_from_plan(sp: StrategyPlan,
                         parallelism=sp.parallelism)
 
 
+# Compile events, tallied process-wide by one listener; a session takes the
+# deltas around its own program calls (``TrainSession.compile_s``).  A
+# backend-compile event is a compile, or a load when the persistent cache
+# served it (a ``cache_hits`` event fires inside it).
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_COMPILE_TALLY = {"seconds": 0.0, "backend": 0, "hits": 0}
+_compile_listener = []
+
+
+def _on_compile_duration(event: str, duration: float, **kw) -> None:
+    if event == _BACKEND_COMPILE:
+        _COMPILE_TALLY["seconds"] += duration
+        _COMPILE_TALLY["backend"] += 1
+
+
+def _on_compile_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT:
+        _COMPILE_TALLY["hits"] += 1
+
+
+def _listen_to_compiles() -> None:
+    if not _compile_listener:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        jax.monitoring.register_event_listener(_on_compile_event)
+        _compile_listener.append(True)
+
+
 def _collapse_mean(tree):
     """Collapse per-worker state (leading world axis, the diverging-
     scheduler carry) to its consensus view: the mean for inexact leaves —
@@ -144,6 +173,20 @@ class TrainSession:
     phase programs.  Rounds accounting: ``grad_rounds`` (gradient syncs),
     ``param_rounds`` (parameter averaging), ``control_rounds`` (LAG scalar
     probes); ``comm_rounds = grad_rounds + param_rounds``.
+
+    Counters: ``dropped_tokens`` / ``routed_tokens``, the MoE token
+    choices dropped to capacity overflow and routed, come back from the
+    device with each step's loss; ``compile_s`` / ``compiles`` /
+    ``cache_hits`` tally the compiles (and persistent-cache loads) of the
+    session's step programs, and ``programs`` keeps each compiled program
+    by phase name (``base``, ``sync``, ``local``, ``probe``, ``reuse``,
+    ``param_round``) for inspection.  Each step runs inside ``jax.profiler``
+    spans on the device trace's clock: ``repro.step`` (a step annotation)
+    around ``repro.input`` (batch, placement, step index and rng),
+    ``repro.probe`` (LAG), ``repro.dispatch`` (the step program's call),
+    ``repro.param_round``, ``repro.loss_wait`` and ``repro.counters``,
+    each with its ``step``; the first step's ``_build`` runs in
+    ``repro.build``.  With no profiler running they cost next to nothing.
     """
 
     def __init__(self, cfg: Optional[SessionConfig] = None,
@@ -187,13 +230,14 @@ class TrainSession:
         self.param_rounds = 0
         self.control_rounds = 0
         # MoE capacity overflow must not vanish silently (DESIGN.md §14):
-        # arm the host-side tap BEFORE the first trace bakes the callback
-        # into the step program; step_once drains it per step.
+        # the step programs return the counts with the loss
         self.dropped_tokens = 0.0
         self.routed_tokens = 0.0
-        if model_cfg.num_experts:
-            from repro.models.moe import enable_drop_tap
-            enable_drop_tap(True)
+        self.compile_s = 0.0
+        self.compiles = 0
+        self.cache_hits = 0
+        self.programs: Dict[str, Any] = {}
+        _listen_to_compiles()
         self.planned: Optional[Dict[str, Any]] = None
         self.layout: Optional[ShardLayout] = None   # set by sharded builds
         self.staged: Optional[StagedModel] = None   # set by pipeline builds
@@ -656,6 +700,11 @@ class TrainSession:
     def _build(self) -> None:
         if self._built:
             return
+        with jax.profiler.TraceAnnotation("repro.build"):
+            self._build_programs()
+
+    def _build_programs(self) -> None:
+        self.programs = {}
         self._sync_state = None
         self._anchor = None
         self._red_state = None
@@ -890,77 +939,105 @@ class TrainSession:
         return NamedSharding(self.mesh,
                              P(tuple(self.axes)) if split else P())
 
+    def _dispatch(self, name: str, fn, *args):
+        """Call step program ``name`` and tally the compile events its call
+        fires.  The first call compiles it ahead of time (the jitted call
+        then finds it in JAX's caches) and keeps it in ``programs``."""
+        t = dict(_COMPILE_TALLY)
+        if name not in self.programs and hasattr(fn, "lower"):
+            self.programs[name] = fn.lower(*args).compile()
+        out = fn(*args)
+        hits = _COMPILE_TALLY["hits"] - t["hits"]
+        self.compile_s += _COMPILE_TALLY["seconds"] - t["seconds"]
+        self.compiles += _COMPILE_TALLY["backend"] - t["backend"] - hits
+        self.cache_hits += hits
+        return out
+
     def step_once(self) -> float:
         """Run one training step under the strategy; returns the loss."""
-        self._build()
         step = self.step
-        batch = jax.device_put(self.data.batch(step), self._batch_sharding())
-        step_i = jnp.asarray(step, jnp.int32)
-        rng_s = jax.random.fold_in(self.rng, step)
+        with jax.profiler.StepTraceAnnotation("repro.step", step_num=step):
+            self._build()
+            with jax.profiler.TraceAnnotation("repro.input", step=step):
+                batch = jax.device_put(self.data.batch(step),
+                                       self._batch_sharding())
+                step_i = jnp.asarray(step, jnp.int32)
+                rng_s = jax.random.fold_in(self.rng, step)
+            if self.strategy is None:
+                with jax.profiler.TraceAnnotation("repro.dispatch",
+                                                  step=step):
+                    self._params, self._opt_state, out = self._dispatch(
+                        "base", self._base, self._params, self._opt_state,
+                        batch, step_i)
+                self.grad_rounds += 1   # BSP syncs gradients every step
+            else:
+                out = self._strategy_step(step, batch, step_i, rng_s)
+            with jax.profiler.TraceAnnotation("repro.loss_wait", step=step):
+                for v in out.values():      # the counters come with it
+                    v.copy_to_host_async()
+                loss = float(out["loss"])
+            if self.model_cfg.num_experts:
+                with jax.profiler.TraceAnnotation("repro.counters",
+                                                  step=step):
+                    dropped, routed = jax.device_get(
+                        (out["moe_dropped"], out["moe_routed"]))
+                self.dropped_tokens += float(dropped)
+                self.routed_tokens += float(routed)
+        self.losses.append(loss)
+        self.step += 1
+        return loss
 
-        if self.strategy is None:
-            self._params, self._opt_state, loss = self._base(
-                self._params, self._opt_state, batch, step_i)
-            self.grad_rounds += 1   # BSP syncs gradients every step
-            loss = float(loss)
-            self.losses.append(loss)
-            self.step += 1
-            self._drain_drops()
-            return loss
-
+    def _strategy_step(self, step: int, batch, step_i, rng_s):
+        """One step of the scheduler-dispatched phase programs; returns the
+        loss output of the program that computed it."""
         sched = self.strategy.scheduler
         probe = None
         if sched.needs_grad_probe:
-            loss_p, grads_w, delta, scale = self._probe(
-                self._params, batch, self._sched_state["g_last"])
-            probe = {"delta": float(delta), "scale": float(scale)}
+            with jax.profiler.TraceAnnotation("repro.probe", step=step):
+                out_p, grads_w, delta, scale = self._dispatch(
+                    "probe", self._probe, self._params, batch,
+                    self._sched_state["g_last"])
+                probe = {"delta": float(delta), "scale": float(scale)}
             self.control_rounds += 1
         action, self._sched_state = sched.round(step, self._sched_state,
                                                 probe)
         synced = None
-        if action.compute == "sync":
-            if sched.needs_grad_probe:
-                self._params, self._opt_state, self._sync_state, synced = \
-                    self._sync(self._params, self._opt_state,
-                               self._sync_state, grads_w, step_i, rng_s)
-                loss = loss_p
+        with jax.profiler.TraceAnnotation("repro.dispatch", step=step):
+            if action.compute == "sync":
+                if sched.needs_grad_probe:
+                    self._params, self._opt_state, self._sync_state, \
+                        synced = self._dispatch(
+                            "sync", self._sync, self._params,
+                            self._opt_state, self._sync_state, grads_w,
+                            step_i, rng_s)
+                    out = out_p
+                else:
+                    self._params, self._opt_state, self._sync_state, out = \
+                        self._dispatch("sync", self._sync, self._params,
+                                       self._opt_state, self._sync_state,
+                                       batch, step_i, rng_s)
+                self.grad_rounds += 1
+            elif action.compute == "reuse":
+                self._params, self._opt_state = self._dispatch(
+                    "reuse", self._reuse, self._params, self._opt_state,
+                    self._sched_state["g_last"], step_i)
+                out = out_p
+            elif action.compute == "local":
+                self._params, self._opt_state, out = self._dispatch(
+                    "local", self._local, self._params, self._opt_state,
+                    batch, step_i)
             else:
-                self._params, self._opt_state, self._sync_state, loss = \
-                    self._sync(self._params, self._opt_state,
-                               self._sync_state, batch, step_i, rng_s)
-            self.grad_rounds += 1
-        elif action.compute == "reuse":
-            self._params, self._opt_state = self._reuse(
-                self._params, self._opt_state, self._sched_state["g_last"],
-                step_i)
-            loss = loss_p
-        elif action.compute == "local":
-            self._params, self._opt_state, loss = self._local(
-                self._params, self._opt_state, batch, step_i)
-        else:
-            raise ValueError(f"unknown action {action.compute!r}")
+                raise ValueError(f"unknown action {action.compute!r}")
         if action.param_round:
-            self._params, self._anchor, self._red_state = self._param_round(
-                self._params, self._anchor, self._red_state, rng_s)
+            with jax.profiler.TraceAnnotation("repro.param_round",
+                                              step=step):
+                self._params, self._anchor, self._red_state = \
+                    self._dispatch("param_round", self._param_round,
+                                   self._params, self._anchor,
+                                   self._red_state, rng_s)
             self.param_rounds += 1
         self._sched_state = sched.commit(self._sched_state, action, synced)
-
-        loss = float(loss)
-        self.losses.append(loss)
-        self.step += 1
-        self._drain_drops()
-        return loss
-
-    def _drain_drops(self) -> None:
-        """Collect the MoE capacity-overflow counts the step's debug
-        callbacks reported (``float(loss)`` already blocked on the step,
-        so they have fired)."""
-        if not self.model_cfg.num_experts:
-            return
-        from repro.models.moe import drain_drop_tap
-        d, r = drain_drop_tap()
-        self.dropped_tokens += d
-        self.routed_tokens += r
+        return out
 
     @property
     def drop_fraction(self) -> float:

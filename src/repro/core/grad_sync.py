@@ -181,6 +181,15 @@ def sharded_plan_from_config(cfg: SyncConfig, grads) -> CommPlan:
 # The executor
 # ---------------------------------------------------------------------------
 
+def _bucket_scopes(buckets):
+    """``enumerate(buckets)``, the loop body of bucket ``j`` traced under
+    the named scope ``grad_sync/bucket_<j>`` (it names the bucket's ops in
+    the compiled program and the device trace)."""
+    for j, bucket in enumerate(buckets):
+        with jax.named_scope("grad_sync"), jax.named_scope(f"bucket_{j}"):
+            yield j, bucket
+
+
 class PlanExecutor:
     """Executes a ``CommPlan``: per-bucket (possibly heterogeneous)
     error-feedback + compression + collective exchange.
@@ -309,7 +318,7 @@ class PlanExecutor:
         out: List[Optional[jnp.ndarray]] = [None] * len(leaves)
         new_errors: List[Optional[jnp.ndarray]] = []
         new_qs: List[Optional[jnp.ndarray]] = []
-        for j, (b, comp) in enumerate(zip(plan.buckets, self.comps)):
+        for j, (b, comp) in _bucket_scopes(zip(plan.buckets, self.comps)):
             if b.compressor == "none":
                 if b.pack and len(b.leaves) > 1:
                     # fused dense exchange: ONE collective for the bucket —
@@ -387,7 +396,7 @@ class PlanExecutor:
         shards: List[jnp.ndarray] = []
         new_errors: List[Optional[jnp.ndarray]] = []
         new_qs: List[Optional[jnp.ndarray]] = []
-        for j, (b, comp) in enumerate(zip(plan.buckets, self.comps)):
+        for j, (b, comp) in _bucket_scopes(zip(plan.buckets, self.comps)):
             if b.compressor == "none":
                 buf = self._pack_bucket(leaves, b.leaves)
                 shards.append(reduce_scatter(buf, b.algo, self.axes) / denom)

@@ -313,24 +313,26 @@ class StagedModel:
     def stage_apply(self, rows, h):
         """One stage: ``rows_per_stage`` period rows applied in sequence
         (the same per-period remat policy as ``transformer.stack_train``).
-        Returns (h, aux)."""
+        Returns (h, aux), ``aux`` summed over the rows as
+        ``transformer.block_train`` gives it."""
         import jax
         import jax.numpy as jnp
+        from repro.models.moe import add_aux, no_aux
         from repro.models.transformer import block_train
 
         cfg, seg = self.cfg, self.seg
         positions = jnp.arange(h.shape[1])[None, :]
-        aux_total = jnp.zeros((), jnp.float32)
+        aux_total = no_aux()
 
         def period_fn(ps, x):
-            a = jnp.zeros((), jnp.float32)
+            a = no_aux()
             for spec, p in zip(seg.period, ps):
                 def blk(p_, h_, spec=spec):
                     return block_train(p_, cfg, spec, h_, positions)
                 if len(seg.period) > 2:
                     blk = jax.checkpoint(blk)
                 x, aux = blk(p, x)
-                a = a + aux
+                a = add_aux(a, aux)
             return x, a
 
         period_fn = jax.checkpoint(period_fn)
@@ -341,18 +343,20 @@ class StagedModel:
             # point, so a row's (sub)graph — and its backward — compiles
             # identically at every stage count (DESIGN.md §9)
             h = jax.lax.optimization_barrier(h)
-            aux_total = aux_total + aux
+            aux_total = add_aux(aux_total, aux)
         return h, aux_total
 
     def loss_tail(self, shared, h, tokens):
         """Head cell: final norm + chunked cross-entropy (stage S-1 owns the
         real value).  Matches ``Model.loss``'s label convention."""
+        import jax
         import jax.numpy as jnp
         from repro.models.layers import rmsnorm
         labels = jnp.concatenate(
             [tokens[:, 1:], -jnp.ones_like(tokens[:, :1])], axis=1)
-        h = rmsnorm(shared["final_norm"], h, eps=self.cfg.norm_eps)
-        return self.model._chunked_xent(shared, h, labels)
+        with jax.named_scope("head"):
+            h = rmsnorm(shared["final_norm"], h, eps=self.cfg.norm_eps)
+            return self.model._chunked_xent(shared, h, labels)
 
 
 def stage_param_bytes(leaf_bytes: Sequence[float], n_stages: int

@@ -117,6 +117,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((q_blk, 1), jnp.float32),
             pltpu.VMEM((q_blk, hd), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(B, KV, G, T, hd).transpose(0, 3, 1, 2, 4).reshape(

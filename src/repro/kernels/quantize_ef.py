@@ -102,6 +102,7 @@ def quantize_ef_pallas(g, e, *, decay: float = 1.0, tile: int = TILE,
         out_shape=[jax.ShapeDtypeStruct((ntiles, tile), jnp.int8),
                    jax.ShapeDtypeStruct((ntiles, tile), jnp.float32),
                    jax.ShapeDtypeStruct((ntiles, 1), jnp.float32)],
+        name="quantize_ef",
         interpret=interpret,
     )(g.reshape(ntiles, tile), e.reshape(ntiles, tile))
     return (q.reshape(-1)[:n], e_new.reshape(-1)[:n], scales.reshape(-1))
@@ -131,6 +132,7 @@ def quantize_pallas(x, *, tile: int = TILE, interpret=None):
         out_specs=[_row_spec(rows, tile), _row_spec(rows, 1)],
         out_shape=[jax.ShapeDtypeStruct((ntiles, tile), jnp.int8),
                    jax.ShapeDtypeStruct((ntiles, 1), jnp.float32)],
+        name="quantize_tiles",
         interpret=interpret,
     )(x.reshape(ntiles, tile))
     return q.reshape(-1)[:n], scales.reshape(-1)
@@ -163,6 +165,7 @@ def dequant_accum_pallas(q, scales, *, tile: int = TILE, interpret=None):
                   pl.BlockSpec((w, rows, 1), lambda i: (0, i, 0))],
         out_specs=_row_spec(rows, tile),
         out_shape=jax.ShapeDtypeStruct((ntiles, tile), jnp.float32),
+        name="dequant_accum",
         interpret=interpret,
     )(q.reshape(w, ntiles, tile), scales.reshape(w, ntiles, 1))
     return out.reshape(-1)[:n]

@@ -79,6 +79,7 @@ def topk_mask_pallas(x, *, ratio: float = 0.01, tile: int = TILE,
         in_specs=[pl.BlockSpec((tile,), lambda i: (i,))],
         out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((m,), x.dtype),
+        name="topk_mask",
         interpret=interpret,
     )(x)
     return out[:n]
@@ -116,6 +117,7 @@ def topk_ef_pallas(g, e, *, ratio: float = 0.01, tile: int = TILE,
                    pl.BlockSpec((tile,), lambda i: (i,))],
         out_shape=[jax.ShapeDtypeStruct((m,), jnp.float32),
                    jax.ShapeDtypeStruct((m,), jnp.float32)],
+        name="topk_ef",
         interpret=interpret,
     )(g, e)
     return y[:n], e_new[:n]

@@ -5,6 +5,16 @@ axes with the GradientSynchronizer's explicit compress + collective path).
 The vanilla step with FSDP sharding is what every (arch x shape) baseline
 dry-run lowers; the comm-optimized step is the paper's §3/§4 machinery and
 is exercised on archs whose parameters fit a pure DP+TP layout.
+
+Every train-step program runs its parts under named scopes, which name the
+compiled program's ops and so the ops of a device trace: ``forward`` for the
+loss and its gradient (backward ops keep JAX's ``transpose(jvp(...))`` name
+stack under it; ops that remat recomputes count there), ``optimizer`` for
+the update and its apply, and ``grad_sync/bucket_<i>`` for each bucket a
+``PlanExecutor`` exchanges.  Each returns the MoE capacity counters with
+the loss: its loss output is ``{"loss", "moe_dropped", "moe_routed"}``
+(``Model.loss_and_counts``; the loss a mean, the counts summed over the
+data shards).
 """
 from __future__ import annotations
 
@@ -23,6 +33,30 @@ from repro.models.sharding_ctx import manual_axes
 from repro.optim import apply_updates, make_optimizer
 
 
+def _forward(model, params, batch):
+    """``(loss, counts, grads)`` under the ``forward`` scope."""
+    with jax.named_scope("forward"):
+        (loss, counts), grads = jax.value_and_grad(
+            model.loss_and_counts, has_aux=True)(params, batch)
+    return loss, counts, grads
+
+
+def _optimize(optimizer, grads, opt_state, params, step):
+    """``(params, opt_state)`` after the update, under ``optimizer``."""
+    with jax.named_scope("optimizer"):
+        updates, opt_state = optimizer.update(grads, opt_state, params, step)
+        return apply_updates(params, updates), opt_state
+
+
+def _step_out(loss, counts, axes=()):
+    """A step's loss output: the loss (the mean over the manual ``axes``)
+    with the counters (their sum)."""
+    if axes:
+        loss = jax.lax.pmean(loss, tuple(axes))
+        counts = jax.lax.psum(counts, tuple(axes))
+    return dict(counts, loss=loss)
+
+
 # ---------------------------------------------------------------------------
 # Vanilla BSP step (survey §2.4.1 baseline) — pjit/XLA collectives
 # ---------------------------------------------------------------------------
@@ -35,31 +69,35 @@ def make_train_step(model: Model, optimizer, microbatches: int = 1):
     the optimizer step and gradient synchronization per-step identical."""
     def train_step(params, opt_state, batch, step):
         if microbatches <= 1:
-            loss, grads = jax.value_and_grad(model.loss)(params, batch)
+            loss, counts, grads = _forward(model, params, batch)
         else:
             B = jax.tree.leaves(batch)[0].shape[0]
             assert B % microbatches == 0, (B, microbatches)
             mb = B // microbatches
 
             def body(acc, i):
-                tot_loss, g_acc = acc
+                tot_loss, c_acc, g_acc = acc
                 bslice = jax.tree.map(
                     lambda x: jax.lax.dynamic_slice_in_dim(x, i * mb, mb, 0),
                     batch)
-                l, g = jax.value_and_grad(model.loss)(params, bslice)
+                l, c, g = _forward(model, params, bslice)
                 g_acc = jax.tree.map(
                     lambda a, gg: a + gg.astype(jnp.float32), g_acc, g)
-                return (tot_loss + l, g_acc), None
+                c_acc = jax.tree.map(jnp.add, c_acc, c)
+                return (tot_loss + l, c_acc, g_acc), None
 
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            (loss_sum, grads), _ = jax.lax.scan(
-                body, (jnp.zeros(()), zeros), jnp.arange(microbatches))
+            no_counts = {"moe_dropped": jnp.zeros(()),
+                         "moe_routed": jnp.zeros(())}
+            (loss_sum, counts, grads), _ = jax.lax.scan(
+                body, (jnp.zeros(()), no_counts, zeros),
+                jnp.arange(microbatches))
             loss = loss_sum / microbatches
             grads = jax.tree.map(lambda g: g / microbatches, grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params, step)
-        params = apply_updates(params, updates)
-        return params, opt_state, loss
+        params, opt_state = _optimize(optimizer, grads, opt_state, params,
+                                      step)
+        return params, opt_state, _step_out(loss, counts)
 
     return train_step
 
@@ -155,17 +193,17 @@ def _make_synced_train_step(model: Model, optimizer, synchronizer, mesh,
             params = jax.tree.map(lambda s: s[0], params)
             opt_state = jax.tree.map(lambda s: s[0], opt_state)
         with manual_region():
-            loss, grads = jax.value_and_grad(model.loss)(params, batch)
+            loss, counts, grads = _forward(model, params, batch)
         grads, sync_state = synchronizer(grads, sync_state, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params, step)
-        params = apply_updates(params, updates)
+        params, opt_state = _optimize(optimizer, grads, opt_state, params,
+                                      step)
         # local losses differ per shard only through data; report the mean
-        loss = jax.lax.pmean(loss, tuple(data_axes))
+        out = _step_out(loss, counts, data_axes)
         sync_state = jax.tree.map(lambda s: s[None], sync_state)
         if per_worker_params:
             params = jax.tree.map(lambda s: s[None], params)
             opt_state = jax.tree.map(lambda s: s[None], opt_state)
-        return params, opt_state, sync_state, loss
+        return params, opt_state, sync_state, out
 
     # Specs describe only the MANUAL (data) axes: params / optimizer state
     # are replicated across them (P() prefix); the batch and the EF state
@@ -232,34 +270,35 @@ def make_sharded_train_step(model: Model, executor, layout, sharded_opt,
         sync_state = jax.tree.map(lambda s: s[0], sync_state)
         opt = jax.tree.map(lambda s: s[0], opt_rows)
         with manual_region():
-            loss, grads = jax.value_and_grad(model.loss)(params, batch)
+            loss, counts, grads = _forward(model, params, batch)
         gshards, sync_state = executor.sync_shards(grads, sync_state, rng)
-        updates, inner = sharded_opt.update(gshards, opt["opt"],
-                                            opt["master"], step)
-        # the add mirrors apply_updates on the replicated path (masters ARE
-        # the f32 params); XLA's per-graph FMA contraction of this add is
-        # the one place the two modes may differ in the last ulp — see the
-        # conformance suite's tolerance notes (DESIGN.md §8)
-        masters = [m + u for m, u in zip(opt["master"], updates)]
+        with jax.named_scope("optimizer"):
+            updates, inner = sharded_opt.update(gshards, opt["opt"],
+                                                opt["master"], step)
+            # the add mirrors apply_updates on the replicated path (masters
+            # ARE the f32 params); XLA's per-graph FMA contraction of this
+            # add is the one place the two modes may differ in the last
+            # ulp — see the conformance suite's tolerance notes (DESIGN.md
+            # §8)
+            masters = [m + u for m, u in zip(opt["master"], updates)]
 
-        # forward edge: gather the updated 1/p master shards back to full
-        # params (in the leaves' own dtypes)
-        leaves = jax.tree.leaves(params)
-        out = [None] * len(leaves)
-        for b, bl, shard in zip(executor.plan.buckets, layout.buckets,
-                                masters):
-            full = all_gather_shards(shard, bl.n, b.algo, axes)
-            off = 0
-            for i, sz in zip(bl.leaves, bl.sizes):
-                out[i] = full[off:off + sz].reshape(
-                    leaves[i].shape).astype(leaves[i].dtype)
-                off += sz
-        new_params = jax.tree.unflatten(jax.tree.structure(params), out)
+            # forward edge: gather the updated 1/p master shards back to
+            # full params (in the leaves' own dtypes)
+            leaves = jax.tree.leaves(params)
+            out = [None] * len(leaves)
+            for b, bl, shard in zip(executor.plan.buckets, layout.buckets,
+                                    masters):
+                full = all_gather_shards(shard, bl.n, b.algo, axes)
+                off = 0
+                for i, sz in zip(bl.leaves, bl.sizes):
+                    out[i] = full[off:off + sz].reshape(
+                        leaves[i].shape).astype(leaves[i].dtype)
+                    off += sz
+            new_params = jax.tree.unflatten(jax.tree.structure(params), out)
 
-        loss = jax.lax.pmean(loss, tuple(data_axes))
         lead = lambda t: jax.tree.map(lambda s: s[None], t)
         return (new_params, lead({"master": masters, "opt": inner}),
-                lead(sync_state), loss)
+                lead(sync_state), _step_out(loss, counts, data_axes))
 
     def step_fn(params, opt_rows, sync_state, batch, step, rng):
         f = jax.shard_map(
@@ -391,6 +430,7 @@ def make_pipeline_train_step(staged, optimizer, engine, mesh,
             return _body(params, opt_state, sync_state, batch, step, rng)
 
     def _body(params, opt_state, sync_state, batch, step, rng):
+        from repro.models.moe import add_aux, no_aux
         shared = params["shared"]
         rows = jax.tree.map(lambda s: s[0], params["rows"])     # (R/S, ...)
         opt = jax.tree_util.tree_map_with_path(
@@ -410,21 +450,23 @@ def make_pipeline_train_step(staged, optimizer, engine, mesh,
             return jax.lax.dynamic_index_in_dim(
                 toks_mb, jnp.clip(m, 0, M - 1), 0, keepdims=False)
 
+        # the payload's aux (balance loss and MoE counters, summed over
+        # the stages so far) rides with the activations
         def stage_fwd(rows_, payload):
             h, aux = staged.stage_apply(rows_, payload["h"])
-            return {"h": h, "aux": payload["aux"] + aux}
+            return {"h": h, "aux": add_aux(payload["aux"], aux)}
 
         def fwd_and_loss(rows_, shared_, payload, toks):
             out = stage_fwd(rows_, payload)
             l = (staged.loss_tail(shared_, out["h"], toks)
-                 + staged.aux_coef * out["aux"])
+                 + staged.aux_coef * out["aux"]["balance"])
             return out, l
 
         f32 = jnp.float32
         zero_payload = {
             "h": jnp.zeros_like(staged.embed_mb(shared, sel_mb(
                 jnp.zeros((), jnp.int32)))),
-            "aux": jnp.zeros((), f32)}
+            "aux": no_aux()}
         buf = jax.tree.map(
             lambda x: jnp.zeros((W,) + x.shape, x.dtype), zero_payload)
         recv_f = zero_payload
@@ -432,62 +474,74 @@ def make_pipeline_train_step(staged, optimizer, engine, mesh,
         g_shared = jax.tree.map(lambda p: jnp.zeros(p.shape, f32), shared)
         g_rows = jax.tree.map(lambda p: jnp.zeros(p.shape, f32), rows)
         loss_sum = jnp.zeros((), f32)
+        counts = {"moe_dropped": jnp.zeros((), f32),
+                  "moe_routed": jnp.zeros((), f32)}
 
         def masked_add(acc, g, m):
             return jax.tree.map(
                 lambda a, d: a + jnp.where(m, d.astype(f32), 0.0), acc, g)
 
-        for k in range(T):
-            # ---- forward slot: F(k - s) ----
-            m_f = k - s_idx
-            x_first = {"h": staged.embed_mb(shared, sel_mb(m_f)),
-                       "aux": jnp.zeros((), f32)}
-            x_in = jax.tree.map(lambda a, b: jnp.where(is_first, a, b),
-                                x_first, recv_f)
-            # stage-interface barrier (paired with the per-row barriers in
-            # stage_apply): the embed/recv select must not fuse into the
-            # stage body, or the S=1 and S>1 backward graphs diverge in
-            # the last ulp (DESIGN.md §9)
-            x_in = jax.lax.optimization_barrier(x_in)
-            out = stage_fwd(rows, x_in)
-            buf = jax.tree.map(lambda b_, x: b_.at[k % W].set(x), buf, x_in)
+        with jax.named_scope("forward"):
+            for k in range(T):
+                # ---- forward slot: F(k - s) ----
+                m_f = k - s_idx
+                x_first = {"h": staged.embed_mb(shared, sel_mb(m_f)),
+                           "aux": no_aux()}
+                x_in = jax.tree.map(lambda a, b: jnp.where(is_first, a, b),
+                                    x_first, recv_f)
+                # stage-interface barrier (paired with the per-row barriers in
+                # stage_apply): the embed/recv select must not fuse into the
+                # stage body, or the S=1 and S>1 backward graphs diverge in
+                # the last ulp (DESIGN.md §9)
+                x_in = jax.lax.optimization_barrier(x_in)
+                out = stage_fwd(rows, x_in)
+                buf = jax.tree.map(lambda b_, x: b_.at[k % W].set(x), buf,
+                                   x_in)
 
-            # ---- backward slot: B(k - 2(S-1) + s) on the F input buffered
-            # at tick k - 2(S-1) + 2s (rematerialized forward) ----
-            m_b = k - 2 * (S - 1) + s_idx
-            valid_b = (m_b >= 0) & (m_b < M)
-            k_f = k - 2 * (S - 1) + 2 * s_idx
-            x_b = jax.tree.map(
-                lambda b_: jax.lax.dynamic_index_in_dim(
-                    b_, jnp.mod(k_f, W), 0, keepdims=False), buf)
-            toks_b = sel_mb(m_b)
-            (out_b, l_b), vjp = jax.vjp(
-                lambda r_, s_, x_: fwd_and_loss(r_, s_, x_, toks_b),
-                rows, shared, x_b)
-            # incoming grad-activation (zeros for the last stage, whose
-            # backward is seeded by the loss cotangent instead)
-            ct_out = jax.tree.map(
-                lambda t: jnp.where(valid_b & ~is_last, t,
-                                    jnp.zeros((), t.dtype)), recv_b)
-            ct_l = jnp.where(valid_b & is_last, jnp.ones((), l_b.dtype),
-                             jnp.zeros((), l_b.dtype))
-            d_rows, d_shared, d_x = vjp((ct_out, ct_l))
-            g_rows = masked_add(g_rows, d_rows, valid_b)
-            g_shared = masked_add(g_shared, d_shared, valid_b)
-            # chain the input cotangent into the embedding (stage 0 owns it)
-            ct_emb = jax.tree.map(
-                lambda t: jnp.where(valid_b & is_first, t,
-                                    jnp.zeros((), t.dtype)), d_x["h"])
-            _, vjp_e = jax.vjp(
-                lambda s_: staged.embed_mb(s_, toks_b), shared)
-            (d_emb,) = vjp_e(ct_emb)
-            g_shared = masked_add(g_shared, d_emb, valid_b & is_first)
-            loss_sum = loss_sum + jnp.where(valid_b & is_last, l_b, 0.0)
+                # ---- backward slot: B(k - 2(S-1) + s) on the F input buffered
+                # at tick k - 2(S-1) + 2s (rematerialized forward) ----
+                m_b = k - 2 * (S - 1) + s_idx
+                valid_b = (m_b >= 0) & (m_b < M)
+                k_f = k - 2 * (S - 1) + 2 * s_idx
+                x_b = jax.tree.map(
+                    lambda b_: jax.lax.dynamic_index_in_dim(
+                        b_, jnp.mod(k_f, W), 0, keepdims=False), buf)
+                toks_b = sel_mb(m_b)
+                (out_b, l_b), vjp = jax.vjp(
+                    lambda r_, s_, x_: fwd_and_loss(r_, s_, x_, toks_b),
+                    rows, shared, x_b)
+                # incoming grad-activation (zeros for the last stage, whose
+                # backward is seeded by the loss cotangent instead)
+                ct_out = jax.tree.map(
+                    lambda t: jnp.where(valid_b & ~is_last, t,
+                                        jnp.zeros((), t.dtype)), recv_b)
+                ct_l = jnp.where(valid_b & is_last, jnp.ones((), l_b.dtype),
+                                 jnp.zeros((), l_b.dtype))
+                d_rows, d_shared, d_x = vjp((ct_out, ct_l))
+                g_rows = masked_add(g_rows, d_rows, valid_b)
+                g_shared = masked_add(g_shared, d_shared, valid_b)
+                # chain the input cotangent into the embedding (stage 0
+                # owns it)
+                ct_emb = jax.tree.map(
+                    lambda t: jnp.where(valid_b & is_first, t,
+                                        jnp.zeros((), t.dtype)), d_x["h"])
+                _, vjp_e = jax.vjp(
+                    lambda s_: staged.embed_mb(s_, toks_b), shared)
+                (d_emb,) = vjp_e(ct_emb)
+                g_shared = masked_add(g_shared, d_emb, valid_b & is_first)
+                loss_sum = loss_sum + jnp.where(valid_b & is_last, l_b, 0.0)
+                # the last stage's remat forward holds the whole stack's
+                # counts
+                aux_b = out_b["aux"]
+                counts = masked_add(counts,
+                                    {"moe_dropped": aux_b["dropped"],
+                                     "moe_routed": aux_b["routed"]},
+                                    valid_b & is_last)
 
-            # ---- boundary exchange: one hop each way ----
-            if S > 1:
-                recv_f = send_recv(out, pipe_axis, +1)
-                recv_b = send_recv(d_x, pipe_axis, -1)
+                # ---- boundary exchange: one hop each way ----
+                if S > 1:
+                    recv_f = send_recv(out, pipe_axis, +1)
+                    recv_b = send_recv(d_x, pipe_axis, -1)
 
         # shared cells: stage 0 holds the embed grads, stage S-1 the
         # loss-tail grads, everyone else exact zeros — one psum combines
@@ -513,11 +567,10 @@ def make_pipeline_train_step(staged, optimizer, engine, mesh,
         # (R/S, ...) stack instead lets XLA compile the elementwise chain
         # differently per shape (DESIGN.md §9)
         p_un = {"shared": shared, "rows": unstack_rows(rows, rps)}
-        updates, opt = optimizer.update(synced, opt, p_un, step)
-        p_un = apply_updates(p_un, updates)
+        p_un, opt = _optimize(optimizer, synced, opt, p_un, step)
 
         loss = jax.lax.psum(loss_sum, pipe_axis) * inv_m
-        loss = jax.lax.pmean(loss, axes)
+        out = _step_out(loss, jax.lax.psum(counts, pipe_axis), axes)
 
         lead_rows = jax.tree_util.tree_map_with_path(
             lambda p, s: s[None] if any(getattr(e, "key", None) == "rows"
@@ -526,7 +579,7 @@ def make_pipeline_train_step(staged, optimizer, engine, mesh,
                  "rows": jax.tree.map(lambda s: s[None],
                                       restack_rows(p_un["rows"]))},
                 lead_rows,
-                jax.tree.map(lambda s: s[None], sync_state_l), loss)
+                jax.tree.map(lambda s: s[None], sync_state_l), out)
 
     batch_spec = {"tokens": P(axes, None)}
     state_spec = P((pipe_axis,) + axes)
@@ -587,13 +640,12 @@ def make_local_train_step(model: Model, optimizer, mesh,
         params = jax.tree.map(lambda s: s[0], params)
         opt_state = jax.tree.map(lambda s: s[0], opt_state)
         with manual_region():
-            loss, grads = jax.value_and_grad(model.loss)(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params, step)
-        params = apply_updates(params, updates)
-        loss = jax.lax.pmean(loss, tuple(data_axes))
+            loss, counts, grads = _forward(model, params, batch)
+        params, opt_state = _optimize(optimizer, grads, opt_state, params,
+                                      step)
         params = jax.tree.map(lambda s: s[None], params)
         opt_state = jax.tree.map(lambda s: s[None], opt_state)
-        return params, opt_state, loss
+        return params, opt_state, _step_out(loss, counts, data_axes)
 
     def step_fn(params, opt_state, batch, step):
         f = jax.shard_map(
@@ -674,10 +726,11 @@ def make_lag_programs(model: Model, optimizer, synchronizer, mesh,
                       data_axes: Sequence[str] = ("data",)):
     """The three LAG programs (host dispatch, DESIGN.md §5/§7):
 
-      * ``probe(params, batch, g_last) -> (loss, grads_w, delta, scale)`` —
+      * ``probe(params, batch, g_last) -> (out, grads_w, delta, scale)`` —
         per-shard backward plus the two globally psum-ed scalars of LAG's
         trigger; the 8-byte scalars are the ONLY wire traffic of a skipped
-        round.  ``grads_w`` returns per-worker (leading axis, sharded).
+        round.  ``out`` is the step's loss output (the loss with the MoE
+        counters); ``grads_w`` returns per-worker (leading axis, sharded).
       * ``sync_apply(params, opt_state, sync_state, grads_w, step, rng)``
         — reduce this step's gradients through the strategy's reducer and
         update; also returns the synchronized gradient (the new ``g_last``).
@@ -691,7 +744,7 @@ def make_lag_programs(model: Model, optimizer, synchronizer, mesh,
     def probe_body(params, batch, g_last):
         from repro.models.sharding_ctx import manual_region
         with manual_region():
-            loss, grads = jax.value_and_grad(model.loss)(params, batch)
+            loss, counts, grads = _forward(model, params, batch)
 
         def sq(t):
             return sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
@@ -701,8 +754,8 @@ def make_lag_programs(model: Model, optimizer, synchronizer, mesh,
             sq(jax.tree.map(lambda a, b: a.astype(jnp.float32) - b,
                             grads, g_last)), axes)
         scale = jax.lax.psum(sq(grads), axes)
-        loss = jax.lax.pmean(loss, axes)
-        return (loss, jax.tree.map(lambda g: g[None], grads), delta, scale)
+        return (_step_out(loss, counts, axes),
+                jax.tree.map(lambda g: g[None], grads), delta, scale)
 
     def probe(params, batch, g_last):
         f = jax.shard_map(
@@ -717,8 +770,8 @@ def make_lag_programs(model: Model, optimizer, synchronizer, mesh,
         g = jax.tree.map(lambda s: s[0], grads_w)
         ss = jax.tree.map(lambda s: s[0], sync_state)
         synced, ss = synchronizer(g, ss, rng)
-        updates, opt_state = optimizer.update(synced, opt_state, params, step)
-        params = apply_updates(params, updates)
+        params, opt_state = _optimize(optimizer, synced, opt_state, params,
+                                      step)
         return (params, opt_state, jax.tree.map(lambda s: s[None], ss),
                 synced)
 
@@ -732,8 +785,7 @@ def make_lag_programs(model: Model, optimizer, synchronizer, mesh,
         return f(params, opt_state, sync_state, grads_w, step, rng)
 
     def reuse_apply(params, opt_state, g_last, step):
-        updates, opt_state = optimizer.update(g_last, opt_state, params, step)
-        return apply_updates(params, updates), opt_state
+        return _optimize(optimizer, g_last, opt_state, params, step)
 
     return probe, sync_apply, reuse_apply
 

@@ -58,13 +58,19 @@ def dec_block_desc(cfg: ModelConfig) -> Dict[str, Any]:
 
 
 def dec_block_train(params, cfg: ModelConfig, x, positions, memory):
-    h = rmsnorm(params["norm1"], x, eps=cfg.norm_eps)
-    x = x + attn.attn_forward(params["self"], cfg, CROSS_SPEC, h, positions)
-    h = rmsnorm(params["norm_x"], x, eps=cfg.norm_eps)
-    k, v = cross_kv(params["cross"], cfg, memory)
-    x = x + cross_attend(params["cross"], cfg, h, k, v)
-    h = rmsnorm(params["norm2"], x, eps=cfg.norm_eps)
-    return x + mlp(params["ffn"], h, cfg.activation)
+    with jax.named_scope("attn"), jax.named_scope("attn"):
+        h = rmsnorm(params["norm1"], x, eps=cfg.norm_eps)
+        h = attn.attn_forward(params["self"], cfg, CROSS_SPEC, h, positions)
+    x = x + h
+    with jax.named_scope("attn"), jax.named_scope("cross"):
+        h = rmsnorm(params["norm_x"], x, eps=cfg.norm_eps)
+        k, v = cross_kv(params["cross"], cfg, memory)
+        h = cross_attend(params["cross"], cfg, h, k, v)
+    x = x + h
+    with jax.named_scope("mlp"):
+        h = rmsnorm(params["norm2"], x, eps=cfg.norm_eps)
+        h = mlp(params["ffn"], h, cfg.activation)
+    return x + h
 
 
 def dec_block_prefill(params, cfg: ModelConfig, x, positions, memory, max_len):

@@ -21,7 +21,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.models import encdec, transformer
+from repro.models import encdec, moe, transformer
 from repro.models.layers import (ParamDesc, abstract_params, embed,
                                  embedding_desc, materialize, norm_desc,
                                  partition_specs, rmsnorm, sharding_rules,
@@ -71,9 +71,11 @@ class Model:
 
     def _embed(self, params, tokens):
         from repro.models.sharding_ctx import constrain
-        x = embed(params["embed"], tokens, scale=self.cfg.embed_scale,
-                  d=self.cfg.d_model).astype(_dtype(self.cfg.compute_dtype))
-        return constrain(x, ("b", None, None))
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], tokens, scale=self.cfg.embed_scale,
+                      d=self.cfg.d_model).astype(
+                          _dtype(self.cfg.compute_dtype))
+            return constrain(x, ("b", None, None))
 
     def _lm_table(self, params):
         return params["embed" if self.cfg.tie_embeddings else "lm_head"]["table"]
@@ -86,13 +88,12 @@ class Model:
             x = self._embed(params, tokens)
             positions = jnp.arange(tokens.shape[1])[None, :]
             h = encdec.decode_train(params["encdec"], cfg, x, positions, memory)
-            return h, jnp.zeros((), jnp.float32)
+            return h, moe.no_aux()
         tokens = batch["tokens"]
         x = self._embed(params, tokens)
         positions = jnp.arange(tokens.shape[1])[None, :]
-        h, aux = transformer.stack_train(params["stack"], cfg, self.plan, x, positions)
-        h = rmsnorm(params["final_norm"], h, eps=cfg.norm_eps)
-        return h, aux
+        return transformer.stack_train(params["stack"], cfg, self.plan, x,
+                                       positions)
 
     def _chunked_xent(self, params, h, labels, mask=None):
         """h: (B, T, d); labels: (B, T). Scan over T chunks; logits are never
@@ -139,12 +140,25 @@ class Model:
     def loss(self, params, batch):
         """Next-token LM loss (+ MoE aux). Labels are tokens shifted left;
         the final position is masked with -1."""
+        return self.loss_and_counts(params, batch)[0]
+
+    def loss_and_counts(self, params, batch):
+        """``(loss, counts)``: :meth:`loss` and the MoE layers' capacity
+        counters summed over the stack, ``{"moe_dropped", "moe_routed"}``
+        (token choices dropped to overflow / routed; 0 without experts).
+        The embedding runs under the ``embed`` scope, the final norm and
+        the cross-entropy under ``head``."""
         tokens = batch["tokens"]
         labels = jnp.concatenate(
             [tokens[:, 1:], -jnp.ones_like(tokens[:, :1])], axis=1)
         h, aux = self._backbone_train(params, batch)
-        nll = self._chunked_xent(params, h, labels)
-        return nll + self.cfg.router_aux_coef * aux
+        with jax.named_scope("head"):
+            if not self.cfg.is_encoder_decoder:
+                h = rmsnorm(params["final_norm"], h, eps=self.cfg.norm_eps)
+            nll = self._chunked_xent(params, h, labels)
+        loss = nll + self.cfg.router_aux_coef * aux["balance"]
+        return loss, {"moe_dropped": aux["dropped"],
+                      "moe_routed": aux["routed"]}
 
     # -- inference ----------------------------------------------------------
 

@@ -20,42 +20,15 @@ from repro.models.layers import ParamDesc, mlp, mlp_desc
 from repro.models.sharding_ctx import constrain
 
 
-# ---------------------------------------------------------------------------
-# Dropped-token tap (ISSUE 9: capacity overflow must not vanish silently)
-# ---------------------------------------------------------------------------
-#
-# Capacity dispatch DROPS tokens that overflow an expert's buffer; with
-# capacity_factor near 1 under a skewed router that is real signal loss the
-# step log used to hide.  The tap is a host-side accumulator fed by
-# ``jax.debug.callback`` — the only side channel that crosses jit/grad/scan
-# without changing every loss signature between here and the train loop.
-# Toggling changes the traced program, so enable it BEFORE the first step
-# compiles (TrainSession does this for MoE archs); counts drain per step via
-# ``drain_drop_tap``.
-
-_DROP_TAP = {"enabled": False, "dropped": 0.0, "routed": 0.0}
+def no_aux() -> Dict[str, jnp.ndarray]:
+    """What a layer without routed experts adds to the aux path (see
+    :func:`moe_ffn`): no balance loss, no token choices routed or dropped."""
+    z = jnp.zeros((), jnp.float32)
+    return {"balance": z, "dropped": z, "routed": z}
 
 
-def enable_drop_tap(enable: bool = True) -> bool:
-    """Turn the tap on/off (returns the previous state).  Must happen
-    before tracing: the callback is baked into the jitted program."""
-    old = _DROP_TAP["enabled"]
-    _DROP_TAP["enabled"] = bool(enable)
-    return old
-
-
-def drain_drop_tap() -> Tuple[float, float]:
-    """Return ``(dropped, routed)`` token-choice counts accumulated since
-    the last drain, and reset.  Callers must block on the step's outputs
-    first (e.g. ``float(loss)``) so the callbacks have fired."""
-    d, r = _DROP_TAP["dropped"], _DROP_TAP["routed"]
-    _DROP_TAP["dropped"] = _DROP_TAP["routed"] = 0.0
-    return d, r
-
-
-def _drop_tap_cb(dropped, routed: float):
-    _DROP_TAP["dropped"] += float(dropped)
-    _DROP_TAP["routed"] += float(routed)
+def add_aux(a, b):
+    return jax.tree.map(jnp.add, a, b)
 
 
 def moe_desc(cfg: ModelConfig) -> Dict[str, ParamDesc]:
@@ -91,8 +64,14 @@ def moe_ffn(params, cfg: ModelConfig, x, *,
             groups: Optional[int] = None,
             ep_axis: Optional[str] = None,
             a2a_variant: str = "direct"
-            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (B, T, d) -> (out, aux_loss).
+            ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """x: (B, T, d) -> (out, aux).
+
+    ``aux`` is what the layer sends down the aux path to the loss:
+    ``balance``, the load-balance loss, and the capacity counters
+    ``routed`` (token choices routed) and ``dropped`` (choices that
+    overflowed their expert's buffer and were dropped), float32 device
+    scalars over this program's tokens (DESIGN.md §14).
 
     Tokens are grouped per data shard (per-group capacity — real
     expert-parallel per-rank semantics).  The scatter/gather run under
@@ -139,71 +118,79 @@ def moe_ffn(params, cfg: ModelConfig, x, *,
                 f"{params['wi_gate'].shape[0]} experts")
 
     xf = constrain(x.reshape(N, d), ("b", None))
-    weights, experts, aux = _route(cfg, xf @ params["router"])
+    with jax.named_scope("router"):
+        weights, experts, balance = _route(cfg, xf @ params["router"])
 
-    eg = constrain(experts.reshape(G, ng * k), ("b", None))
-    wg = weights.reshape(G, ng * k)
-    onehot = constrain(jax.nn.one_hot(eg, E, dtype=jnp.int32), ("b", None, None))
-    slot = (jnp.cumsum(onehot, axis=1) - 1) * onehot              # per-group
-    flat_slot = slot.sum(-1)
-    keep = flat_slot < cap
-    dest = jnp.where(keep, eg * cap + flat_slot, E * cap)         # (G, ng*k)
-    if _DROP_TAP["enabled"]:
-        jax.debug.callback(_drop_tap_cb, (~keep).sum(), float(keep.size))
+    with jax.named_scope("dispatch"):
+        eg = constrain(experts.reshape(G, ng * k), ("b", None))
+        wg = weights.reshape(G, ng * k)
+        onehot = constrain(jax.nn.one_hot(eg, E, dtype=jnp.int32),
+                           ("b", None, None))
+        slot = (jnp.cumsum(onehot, axis=1) - 1) * onehot          # per-group
+        flat_slot = slot.sum(-1)
+        keep = flat_slot < cap
+        dest = jnp.where(keep, eg * cap + flat_slot, E * cap)     # (G, ng*k)
+        aux = {"balance": balance,
+               "dropped": jnp.sum(~keep).astype(jnp.float32),
+               "routed": jnp.asarray(keep.size, jnp.float32)}
 
-    tok_idx = jnp.repeat(jnp.arange(ng), k)
-    xg = constrain(xf.reshape(G, ng, d), ("b", None, None))
-    src = constrain(jnp.take(xg, tok_idx, axis=1), ("b", None, None))
+        tok_idx = jnp.repeat(jnp.arange(ng), k)
+        xg = constrain(xf.reshape(G, ng, d), ("b", None, None))
+        src = constrain(jnp.take(xg, tok_idx, axis=1), ("b", None, None))
 
-    def scatter_one(s, idx):
-        return jnp.zeros((E * cap + 1, d), cdt).at[idx].set(s)[: E * cap]
+        def scatter_one(s, idx):
+            return jnp.zeros((E * cap + 1, d), cdt).at[idx].set(s)[: E * cap]
 
-    buf = jax.vmap(scatter_one)(src, dest)                        # (G, E*cap, d)
-    buf = constrain(buf.reshape(G, E, cap, d), ("b", "m", None, None))
+        buf = jax.vmap(scatter_one)(src, dest)            # (G, E*cap, d)
+        buf = constrain(buf.reshape(G, E, cap, d), ("b", "m", None, None))
 
-    if ep_axis is not None:
-        from repro.core.collectives.api import all_to_all
-        El = E // ep
-        # dispatch: chunk s of the capacity buffer is the payload for ep
-        # rank s (its expert block, GLOBAL expert order = rank-major)
-        b = all_to_all(buf.reshape(ep, El * cap, d), ep_axis, a2a_variant)
-        b = b.reshape(ep, El, cap, d)         # row s: source rank s's tokens
-        h_gate = jax.nn.silu(jnp.einsum("secd,edf->secf", b,
-                                        params["wi_gate"]))
-        h_up = jnp.einsum("secd,edf->secf", b, params["wi_up"])
-        h_mid = (h_gate * h_up).astype(cdt)
-        out_b = jnp.einsum("secf,efd->secd", h_mid, params["wo"])
-        # combine: the reverse all-to-all returns each token's outputs to
-        # its owner, re-assembling the (E, cap, d) buffer in global order
-        out_flat = all_to_all(out_b.reshape(ep, El * cap, d), ep_axis,
-                              a2a_variant).reshape(G, E * cap, d)
-    else:
-        h_gate = jax.nn.silu(jnp.einsum("gecd,edf->gecf", buf,
-                                        params["wi_gate"]))
-        h_up = jnp.einsum("gecd,edf->gecf", buf, params["wi_up"])
-        h_mid = constrain((h_gate * h_up).astype(cdt),
-                          ("b", "m", None, None))
-        out_buf = constrain(jnp.einsum("gecf,efd->gecd", h_mid,
-                                       params["wo"]),
-                            ("b", "m", None, None))
-        out_flat = constrain(out_buf.reshape(G, E * cap, d),
-                             ("b", None, None))
+    with jax.named_scope("experts"):
+        if ep_axis is not None:
+            from repro.core.collectives.api import all_to_all
+            El = E // ep
+            # dispatch: chunk s of the capacity buffer is the payload for ep
+            # rank s (its expert block, GLOBAL expert order = rank-major)
+            b = all_to_all(buf.reshape(ep, El * cap, d), ep_axis,
+                           a2a_variant)
+            b = b.reshape(ep, El, cap, d)   # row s: source rank s's tokens
+            h_gate = jax.nn.silu(jnp.einsum("secd,edf->secf", b,
+                                            params["wi_gate"]))
+            h_up = jnp.einsum("secd,edf->secf", b, params["wi_up"])
+            h_mid = (h_gate * h_up).astype(cdt)
+            out_b = jnp.einsum("secf,efd->secd", h_mid, params["wo"])
+            # combine: the reverse all-to-all returns each token's outputs to
+            # its owner, re-assembling the (E, cap, d) buffer in global order
+            out_flat = all_to_all(out_b.reshape(ep, El * cap, d), ep_axis,
+                                  a2a_variant).reshape(G, E * cap, d)
+        else:
+            h_gate = jax.nn.silu(jnp.einsum("gecd,edf->gecf", buf,
+                                            params["wi_gate"]))
+            h_up = jnp.einsum("gecd,edf->gecf", buf, params["wi_up"])
+            h_mid = constrain((h_gate * h_up).astype(cdt),
+                              ("b", "m", None, None))
+            out_buf = constrain(jnp.einsum("gecf,efd->gecd", h_mid,
+                                           params["wo"]),
+                                ("b", "m", None, None))
+            out_flat = constrain(out_buf.reshape(G, E * cap, d),
+                                 ("b", None, None))
 
-    def gather_one(flat, idx, kp):
-        g = jnp.take(flat, jnp.minimum(idx, E * cap - 1), axis=0)
-        return jnp.where(kp[:, None], g, 0.0)
+    with jax.named_scope("combine"):
+        def gather_one(flat, idx, kp):
+            g = jnp.take(flat, jnp.minimum(idx, E * cap - 1), axis=0)
+            return jnp.where(kp[:, None], g, 0.0)
 
-    gathered = jax.vmap(gather_one)(out_flat, dest, keep)         # (G, ng*k, d)
-    contrib = gathered * wg[..., None].astype(gathered.dtype)
+        gathered = jax.vmap(gather_one)(out_flat, dest, keep)  # (G, ng*k, d)
+        contrib = gathered * wg[..., None].astype(gathered.dtype)
 
-    def combine_one(c):
-        return jnp.zeros((ng, d), cdt).at[tok_idx].add(c)
+        def combine_one(c):
+            return jnp.zeros((ng, d), cdt).at[tok_idx].add(c)
 
-    out = constrain(jax.vmap(combine_one)(contrib), ("b", None, None))
-    out = out.reshape(N, d)
+        out = constrain(jax.vmap(combine_one)(contrib), ("b", None, None))
+        out = out.reshape(N, d)
 
     if cfg.num_shared_experts:
-        out = out + mlp(params["shared"], xf, cfg.activation)
+        with jax.named_scope("shared"):
+            out = out + mlp(params["shared"], xf, cfg.activation)
     return out.reshape(B, T, d), aux
 
 

@@ -64,9 +64,11 @@ def manual_region():
 def manual_axes(mesh, axes: Sequence[str]) -> set:
     """``axis_names`` for a shard_map manual over ``axes``: those axes plus
     every size-1 axis of ``mesh``.  A one-shard axis computes the same
-    manual or auto, but left auto it makes the body partial-manual, and
-    XLA aborts on a host callback there (the MoE drop tap on the standard
-    ``data(N) x model(1)`` session mesh)."""
+    manual or auto; named manual, it keeps the body full-manual on the
+    standard ``data(N) x model(1)`` session mesh rather than
+    partial-manual, the form every multi-device check pins.  (It was
+    introduced because XLA aborted on a host callback in a partial-manual
+    body; no step program carries a host callback now.)"""
     return set(axes) | {a for a in mesh.axis_names if mesh.shape[a] == 1}
 
 

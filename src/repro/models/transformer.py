@@ -62,33 +62,43 @@ def _boundary(h):
 
 def block_train(params, cfg: ModelConfig, spec: LayerSpec, x, positions,
                 causal: bool = True):
-    """Full-sequence block. Returns (x, aux)."""
-    aux = jnp.zeros((), jnp.float32)
+    """Full-sequence block. Returns (x, aux), ``aux`` as
+    :func:`moe.moe_ffn` gives it (:func:`moe.no_aux` without experts).
+
+    The parts run under named scopes, which name their ops in the compiled
+    program and the device trace: ``attn/<mixer>`` for the mixer (its
+    norm included), ``moe`` or ``mlp`` for the FFN."""
+    aux = moe_mod.no_aux()
     if spec.mixer in ("mlstm", "slstm"):
         f = xlstm_mod.mlstm_forward if spec.mixer == "mlstm" else xlstm_mod.slstm_forward
-        return x + _boundary(f(params["mixer"], cfg, x)), aux
-    h = rmsnorm(params["norm1"], x, eps=cfg.norm_eps)
-    if spec.mixer == "attn":
-        if causal:
-            h = attn.attn_forward(params["mixer"], cfg, spec, h, positions)
-        else:  # encoder self-attention
-            h = _attn_bidirectional(params["mixer"], cfg, spec, h, positions)
-    elif spec.mixer == "mla":
-        h = attn.mla_forward(params["mixer"], cfg, spec, h, positions)
-    else:  # mamba
-        h = ssm_mod.mamba_forward(params["mixer"], cfg, h)
+        with jax.named_scope("attn"), jax.named_scope(spec.mixer):
+            h = f(params["mixer"], cfg, x)
+        return x + _boundary(h), aux
+    with jax.named_scope("attn"), jax.named_scope(spec.mixer):
+        h = rmsnorm(params["norm1"], x, eps=cfg.norm_eps)
+        if spec.mixer == "attn":
+            if causal:
+                h = attn.attn_forward(params["mixer"], cfg, spec, h, positions)
+            else:  # encoder self-attention
+                h = _attn_bidirectional(params["mixer"], cfg, spec, h,
+                                        positions)
+        elif spec.mixer == "mla":
+            h = attn.mla_forward(params["mixer"], cfg, spec, h, positions)
+        else:  # mamba
+            h = ssm_mod.mamba_forward(params["mixer"], cfg, h)
     x = x + _boundary(h)
     if spec.ffn != "none":
-        h = rmsnorm(params["norm2"], x, eps=cfg.norm_eps)
-        if spec.ffn == "moe":
-            h, aux = moe_mod.moe_ffn(params["ffn"], cfg, h)
-        elif tp_axis():
-            # manual tensor parallelism (DESIGN.md §14): params hold this
-            # rank's ffn slice; the Megatron f/g wire reduces activations
-            # over the tp axis via collectives.api
-            h = mlp_tp(params["ffn"], h, cfg.activation, axis=tp_axis())
-        else:
-            h = mlp(params["ffn"], h, cfg.activation)
+        with jax.named_scope("moe" if spec.ffn == "moe" else "mlp"):
+            h = rmsnorm(params["norm2"], x, eps=cfg.norm_eps)
+            if spec.ffn == "moe":
+                h, aux = moe_mod.moe_ffn(params["ffn"], cfg, h)
+            elif tp_axis():
+                # manual tensor parallelism (DESIGN.md §14): params hold
+                # this rank's ffn slice; the Megatron f/g wire reduces
+                # activations over the tp axis via collectives.api
+                h = mlp_tp(params["ffn"], h, cfg.activation, axis=tp_axis())
+            else:
+                h = mlp(params["ffn"], h, cfg.activation)
         x = x + _boundary(h)
     return x, aux
 
@@ -104,7 +114,7 @@ def _attn_bidirectional(params, cfg, spec, x, positions):
 def block_prefill(params, cfg: ModelConfig, spec: LayerSpec, x, positions,
                   max_len: int):
     """Full-sequence block that also emits this layer's decode cache."""
-    aux = jnp.zeros((), jnp.float32)
+    aux = moe_mod.no_aux()
     if spec.mixer in ("mlstm", "slstm"):
         f = xlstm_mod.mlstm_forward if spec.mixer == "mlstm" else xlstm_mod.slstm_forward
         h, cache = f(params["mixer"], cfg, x, return_state=True)
@@ -209,11 +219,11 @@ def stack_train(params_segs, cfg: ModelConfig, plan, x, positions,
     rematerialized, so the backward pass stores O(outer + inner) layer
     inputs instead of O(repeats) — the sqrt-remat policy that keeps the
     95-layer configs inside 16 GB/chip."""
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = moe_mod.no_aux()
     for seg, seg_params in zip(plan, params_segs):
         def period_fn(ps, h, seg=seg):
             h = constrain(h, ("b", None, None))
-            a = jnp.zeros((), jnp.float32)
+            a = moe_mod.no_aux()
             for spec, p in zip(seg.period, ps):
                 def blk(p_, h_, spec=spec):
                     return block_train(p_, cfg, spec, h_, positions, causal)
@@ -223,7 +233,7 @@ def stack_train(params_segs, cfg: ModelConfig, plan, x, positions,
                     # backward holds one block's intermediates at a time
                     blk = jax.checkpoint(blk)
                 h, aux = blk(p, h)
-                a = a + aux
+                a = moe_mod.add_aux(a, aux)
             return h, a
 
         if remat:
@@ -231,13 +241,13 @@ def stack_train(params_segs, cfg: ModelConfig, plan, x, positions,
 
         if seg.repeats == 1:
             x, aux = period_fn(seg_params, x)
-            aux_total += aux
+            aux_total = moe_mod.add_aux(aux_total, aux)
             continue
 
         def body(carry, ps, fn=period_fn):
             h, a = carry
             h, aux = fn(ps, h)
-            return (h, a + aux), None
+            return (h, moe_mod.add_aux(a, aux)), None
 
         inner = _sqrt_factor(seg.repeats) if remat else 1
         if inner <= 1:
@@ -262,14 +272,14 @@ def stack_train(params_segs, cfg: ModelConfig, plan, x, positions,
 def stack_prefill(params_segs, cfg: ModelConfig, plan, x, positions,
                   max_len: int):
     """Returns (x, aux_total, cache) where cache mirrors stack_cache()."""
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = moe_mod.no_aux()
     caches = []
     for seg, seg_params in zip(plan, params_segs):
         if seg.repeats == 1:
             seg_caches = []
             for spec, p in zip(seg.period, seg_params):
                 x, aux, c = block_prefill(p, cfg, spec, x, positions, max_len)
-                aux_total += aux
+                aux_total = moe_mod.add_aux(aux_total, aux)
                 seg_caches.append(c)
             caches.append(seg_caches)
         else:
@@ -278,7 +288,7 @@ def stack_prefill(params_segs, cfg: ModelConfig, plan, x, positions,
                 cs = []
                 for spec, p in zip(seg.period, ps):
                     h, aux, c = block_prefill(p, cfg, spec, h, positions, max_len)
-                    a = a + aux
+                    a = moe_mod.add_aux(a, aux)
                     cs.append(c)
                 return (h, a), cs
 

@@ -356,7 +356,8 @@ def check_pipeline_bit_exact():
                                  jax.random.fold_in(rng, s))
         from repro.launch.steps import merge_opt_rows
         merged = model.merge(p["shared"], p["rows"])
-        return merged, merge_opt_rows(o, model.layout.rows), float(loss)
+        return (merged, merge_opt_rows(o, model.layout.rows),
+                float(loss["loss"]))
 
     for opt_name, comp, algo, exact in (
             ("adam", "none", "psum", True),
@@ -421,7 +422,8 @@ def check_pipeline_matches_classic_dp_step():
     pp, op, _, lp = jax.jit(pstep)(pp, op, sp, batch, step_i, rng)
     merged = model.merge(pp["shared"], pp["rows"])
 
-    assert abs(float(lc) - float(lp)) < 1e-6, (float(lc), float(lp))
+    lc, lp = float(lc["loss"]), float(lp["loss"])
+    assert abs(lc - lp) < 1e-6, (lc, lp)
     for k in ("emb", "out", "b"):
         np.testing.assert_allclose(np.asarray(merged[k]),
                                    np.asarray(pc[k]),
@@ -945,16 +947,14 @@ def check_ep_dp_bit_exact():
           "(direct/ring a2a, expert params + adam moments, 3 steps)")
 
 
-def check_drop_tap_shard_map():
-    """The MoE drop tap (DESIGN.md §14) must survive the shard_map sync
-    paths.  A host callback baked into a shard_map body manual over the
-    data axes, with a size-1 auto model axis left over on the same mesh,
-    makes XLA abort the process, and ``data(N) × model(1)`` is the
-    standard session mesh every multi-device ``--sync comm`` /
-    ``--parallelism`` run shard_maps over.  The step builders therefore
-    name size-1 axes manual too (``sharding_ctx.manual_axes``, a no-op for
-    the math), so the body is full-manual and the tap FIRES.  A live (>1)
-    auto model axis left over is fine, and the tap fires there too."""
+def check_moe_counts_shard_map():
+    """The MoE capacity counters (DESIGN.md §14) come back from a
+    shard_map step body as device scalars: each data shard routes its own
+    tokens against its own capacity, and the psum over the data axis is
+    the sum of the shards' counts.  Checked against the same routing run
+    shard by shard outside shard_map, with the size-1 model axis named
+    manual (``data(8) x model(1)``, the standard session mesh) and with a
+    live model axis left auto (``data(4) x model(2)``)."""
     from repro.configs.base import ModelConfig
     from repro.models import moe
     from repro.models.sharding_ctx import manual_axes, manual_region, mesh_ctx
@@ -973,29 +973,30 @@ def check_drop_tap_shard_map():
 
     def body(p, xs):
         with manual_region():
-            out, _ = moe.moe_ffn(p, cfg, xs)
-        return jax.lax.psum(jnp.sum(out ** 2), "data")
+            _, aux = moe.moe_ffn(p, cfg, xs)
+        return jax.lax.psum((aux["dropped"], aux["routed"]), "data")
 
-    old = moe.enable_drop_tap(True)
-    try:
-        for shape in ((8, 1), (4, 2)):
-            mesh = jax.make_mesh(shape, ("data", "model"),
-                                 axis_types=(AxisType.Auto,) * 2)
-            with mesh_ctx(mesh, ("data",)):
-                f = jax.jit(jax.shard_map(
-                    body, mesh=mesh,
-                    in_specs=({k: P() for k in params}, P("data")),
-                    out_specs=P(), axis_names=manual_axes(mesh, ("data",)),
-                    check_vma=False))
-                moe.drain_drop_tap()
-                float(f(params, x))        # blocks → callbacks have fired
-            dropped, routed = moe.drain_drop_tap()
-            assert routed == x.shape[0] * x.shape[1] * cfg.top_k, routed
-            assert dropped > 0, (shape, dropped, routed)  # cap 0.5 drops
-    finally:
-        moe.enable_drop_tap(old)
-    print("moe drop tap under shard_map ok (data(8) x model(1): size-1 "
-          "axis named manual; data(4) x model(2): model axis left auto)")
+    for shape in ((8, 1), (4, 2)):
+        n = shape[0]
+        want_d, want_r = 0.0, 0.0
+        for xs in np.split(np.asarray(x), n):
+            _, aux = jax.jit(lambda v: moe.moe_ffn(params, cfg, v))(xs)
+            want_d += float(aux["dropped"])
+            want_r += float(aux["routed"])
+        mesh = jax.make_mesh(shape, ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+        with mesh_ctx(mesh, ("data",)):
+            f = jax.jit(jax.shard_map(
+                body, mesh=mesh,
+                in_specs=({k: P() for k in params}, P("data")),
+                out_specs=P(), axis_names=manual_axes(mesh, ("data",)),
+                check_vma=False))
+            dropped, routed = (float(v) for v in f(params, x))
+        assert routed == want_r == x.shape[0] * x.shape[1] * cfg.top_k, \
+            (shape, routed, want_r)
+        assert dropped == want_d > 0, (shape, dropped, want_d)
+    print("moe capacity counters under shard_map ok (data(8) x model(1), "
+          "data(4) x model(2): the sum of the shards' counts)")
 
 
 if __name__ == "__main__":
@@ -1019,5 +1020,5 @@ if __name__ == "__main__":
     check_all_to_all_bit_identity()
     check_tp_dp_bit_exact()
     check_ep_dp_bit_exact()
-    check_drop_tap_shard_map()
+    check_moe_counts_shard_map()
     print("ALL MULTI-DEVICE CHECKS PASSED")

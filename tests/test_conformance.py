@@ -85,7 +85,7 @@ def _run_replicated(model, params0, plan, opt_name, steps=STEPS):
         p, os_, ss, loss = jit(p, os_, ss, tiny_batch(s),
                                jnp.asarray(s, jnp.int32),
                                jax.random.fold_in(jax.random.PRNGKey(1), s))
-        losses.append(float(loss))
+        losses.append(float(loss["loss"]))
     # strip the leading per-worker axis from the sync state (world=1)
     return p, os_, jax.tree.map(lambda x: x[0], ss), losses
 
@@ -104,7 +104,7 @@ def _run_sharded(model, params0, plan, opt_name, steps=STEPS):
         p, rows, ss, loss = jit(p, rows, ss, tiny_batch(s),
                                 jnp.asarray(s, jnp.int32),
                                 jax.random.fold_in(jax.random.PRNGKey(1), s))
-        losses.append(float(loss))
+        losses.append(float(loss["loss"]))
     return p, rows, jax.tree.map(lambda x: x[0], ss), losses, layout
 
 

@@ -150,4 +150,5 @@ def test_moe_routing_mass_conservation():
                  "wo": seg["wo"][0, 0]}, x, cfg.activation)
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                rtol=2e-2, atol=2e-2)
-    assert jnp.isfinite(aux)
+    assert jnp.isfinite(aux["balance"])
+    assert float(aux["dropped"]) == 0.0          # capacity for every choice

@@ -228,7 +228,7 @@ def test_memory_budget_can_select_model_axis():
 
 
 # ---------------------------------------------------------------------------
-# MoE capacity overflow: the drop tap (satellite c)
+# MoE capacity overflow: the device counter (DESIGN.md §14)
 # ---------------------------------------------------------------------------
 
 def _moe_cfg(capacity_factor):
@@ -248,55 +248,74 @@ def _moe_params(cfg):
             "wo": jax.random.normal(ks[3], (E, ff, d))}
 
 
+def _recount_drops(cfg, params, x):
+    """NumPy recount of the capacity dispatch: each token's k choices take
+    the next free slot of their expert in (token, choice) order, and a
+    choice past the expert's capacity is dropped.  Returns (dropped,
+    routed)."""
+    xf = np.asarray(x, np.float32).reshape(-1, cfg.d_model)
+    logits = xf @ np.asarray(params["router"], np.float32)
+    experts = np.argsort(-logits, axis=-1, kind="stable")[:, :cfg.top_k]
+    n = xf.shape[0]
+    cap = int(max(1, n * cfg.top_k / cfg.num_experts * cfg.capacity_factor))
+    load = np.zeros(cfg.num_experts, np.int64)
+    dropped = 0
+    for e in experts.reshape(-1):
+        dropped += load[e] >= cap
+        load[e] += 1
+    return float(dropped), float(n * cfg.top_k)
+
+
 def test_moe_forced_overflow_surfaces_dropped_tokens():
-    """capacity_factor far below the routing skew MUST report drops — the
-    silent-token-drop regression this PR fixes.  The tap crosses jit and
-    grad (jax.debug.callback), counts drain-and-reset, and an ample
-    capacity reports zero."""
+    """capacity_factor far below the routing skew MUST report drops, as
+    device scalars beside the balance loss: exactly the NumPy recount of
+    the same routing, in the forward program and in the grad program
+    (where training meets them), and zero drops at ample capacity."""
     from repro.models import moe
 
     x = jax.random.normal(jax.random.PRNGKey(4), (8, 4, 16))
-    was = moe.enable_drop_tap(True)
-    try:
-        cfg = _moe_cfg(0.25)                      # forced overflow
-        out, _ = jax.jit(lambda v: moe.moe_ffn(_moe_params(cfg), cfg, v))(x)
-        out.block_until_ready()
-        dropped, routed = moe.drain_drop_tap()
-        assert routed == 8 * 4 * cfg.top_k
-        assert dropped > 0
-        # drained -> reset
-        assert moe.drain_drop_tap() == (0.0, 0.0)
+    cfg = _moe_cfg(0.25)                          # forced overflow
+    params = _moe_params(cfg)
+    _, aux = jax.jit(lambda v: moe.moe_ffn(params, cfg, v))(x)
+    want = _recount_drops(cfg, params, x)
+    assert want[0] > 0 and want[1] == 8 * 4 * cfg.top_k
+    assert (float(aux["dropped"]), float(aux["routed"])) == want
 
-        # the tap must survive the grad program too (training is where the
-        # drops actually bite)
-        cfg2 = _moe_cfg(0.25)
-        g = jax.jit(jax.grad(lambda v: jnp.sum(
-            moe.moe_ffn(_moe_params(cfg2), cfg2, v)[0] ** 2)))(x)
-        jax.block_until_ready(g)
-        dropped, routed = moe.drain_drop_tap()
-        assert dropped > 0 and routed > 0
+    def loss(v):
+        out, a = moe.moe_ffn(params, cfg, v)
+        return jnp.sum(out ** 2) + a["balance"], a
+    (_, aux), g = jax.jit(jax.value_and_grad(loss, has_aux=True))(x)
+    assert np.all(np.isfinite(np.asarray(g)))
+    assert (float(aux["dropped"]), float(aux["routed"])) == want
 
-        cfg3 = _moe_cfg(8.0)                      # ample capacity
-        out, _ = jax.jit(lambda v: moe.moe_ffn(_moe_params(cfg3), cfg3, v))(x)
-        out.block_until_ready()
-        dropped, routed = moe.drain_drop_tap()
-        assert (dropped, routed) == (0.0, 8 * 4 * cfg3.top_k)
-    finally:
-        moe.enable_drop_tap(was)
+    cfg3 = _moe_cfg(8.0)                          # ample capacity
+    _, aux = jax.jit(lambda v: moe.moe_ffn(_moe_params(cfg3), cfg3, v))(x)
+    assert (float(aux["dropped"]), float(aux["routed"])) == (
+        0.0, 8 * 4 * cfg3.top_k)
+
+
+def _session(arch, **kw):
+    from repro.api import SessionConfig, TrainSession
+    return TrainSession(SessionConfig(arch=arch, reduced=True, batch=2,
+                                      seq=16, steps=4, **kw))
 
 
 def test_moe_drop_tap_disabled_counts_nothing():
-    from repro.models import moe
+    """The session's counters read only what its own steps returned: a
+    dense model routes nothing, and an MoE session's counts do not leak
+    into the next session (the same first step counts the same)."""
+    dense = _session("gemma-2b")
+    dense.step_once()
+    assert (dense.dropped_tokens, dense.routed_tokens) == (0.0, 0.0)
 
-    was = moe.enable_drop_tap(False)
-    try:
-        cfg = _moe_cfg(0.25)
-        x = jax.random.normal(jax.random.PRNGKey(5), (4, 4, 16))
-        out, _ = jax.jit(lambda v: moe.moe_ffn(_moe_params(cfg), cfg, v))(x)
-        out.block_until_ready()
-        assert moe.drain_drop_tap() == (0.0, 0.0)
-    finally:
-        moe.enable_drop_tap(was)
+    a = _session("deepseek-v2-lite-16b")
+    a.step_once()
+    first = (a.dropped_tokens, a.routed_tokens)
+    a.step_once()
+    assert a.routed_tokens == 2 * first[1] > 0
+    b = _session("deepseek-v2-lite-16b")
+    b.step_once()
+    assert (b.dropped_tokens, b.routed_tokens) == first
 
 
 def test_render_moe_drops_report():

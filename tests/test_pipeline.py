@@ -174,7 +174,7 @@ def _pipeline_step_once(model, params, batch, M, opt_name="sgd", lr=0.1):
     p2, _, _, loss = jax.jit(step_fn)(p, o, ss, batch,
                                       jnp.zeros((), jnp.int32),
                                       jax.random.PRNGKey(1))
-    return model.merge(p2["shared"], p2["rows"]), float(loss)
+    return model.merge(p2["shared"], p2["rows"]), float(loss["loss"])
 
 
 def test_microbatch_accumulation_bit_exact_vs_scan_reference():

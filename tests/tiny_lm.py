@@ -1,6 +1,7 @@
 """Tiny embedding+linear LM, duck-typed like ``repro.models.Model`` (has
-``loss(params, batch)`` over {'tokens': (B, T)}): the conformance suite's
-workhorse — big enough to fuse into multiple buckets, small enough that a
+``loss(params, batch)`` and ``loss_and_counts(params, batch)`` over
+{'tokens': (B, T)}; no experts, so its MoE counters are 0): the
+conformance suite's workhorse — big enough to fuse into multiple buckets, small enough that a
 strategy × wire × mode sweep trains in seconds.  Shared by
 test_conformance.py and the multi_device_checks.py subprocess.
 """
@@ -8,6 +9,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.models.moe import no_aux
+
+
+def _no_counts():
+    z = jnp.zeros((), jnp.float32)
+    return {"moe_dropped": z, "moe_routed": z}
 
 
 class TinyLM:
@@ -26,6 +34,9 @@ class TinyLM:
         logits = x @ params["out"] + params["b"]
         lp = jax.nn.log_softmax(logits)
         return -jnp.mean(jnp.take_along_axis(lp, toks[:, 1:][..., None], -1))
+
+    def loss_and_counts(self, params, batch):
+        return self.loss(params, batch), _no_counts()
 
 
 def tiny_batch(step: int, batch: int = 8, seq: int = 16, vocab: int = 64):
@@ -101,7 +112,7 @@ class TinyStackLM:
             # the stage-count bit-exactness contract (DESIGN.md §9)
             h = jax.lax.optimization_barrier(
                 h + jnp.tanh(h @ w1 + b1) @ w2)
-        return h, jnp.zeros((), jnp.float32)
+        return h, no_aux()
 
     def loss_tail(self, shared, h, tokens):
         logits = h @ shared["out"] + shared["b"]
@@ -120,3 +131,6 @@ class TinyStackLM:
             w1, b1, w2 = rows["w1"][i], rows["b1"][i], rows["w2"][i]
             h = h + jnp.tanh(h @ w1 + b1) @ w2
         return self.loss_tail(shared, h, batch["tokens"])
+
+    def loss_and_counts(self, params, batch):
+        return self.loss(params, batch), _no_counts()
