@@ -4,12 +4,18 @@ dispatch, and a switch-style load-balance auxiliary loss.
 Dispatch is the sort-free capacity scheme: each token's k choices are given a
 slot inside the chosen expert's capacity buffer via a cumulative-sum over the
 one-hot routing matrix; tokens overflowing capacity are dropped (standard
-practice, capacity_factor controls the drop rate).  With experts sharded over
-the ``model`` mesh axis the scatter/gather lower to all-to-all style
-collectives — the expert-parallel pattern the survey's §4 discusses.
+practice, capacity_factor controls the drop rate).  Kept choices and filled
+slots are in bijection, so rows move between tokens and the buffer by row
+gathers alone, forward and backward (``_dispatch`` and ``_combine``, a
+``custom_vjp`` pair whose backward gathers through the inverse map where
+autodiff would scatter-add); only the int32 slot map is built by a scatter.
+With experts sharded over the ``model`` mesh axis the buffer moves by
+all-to-all style collectives — the expert-parallel pattern the survey's §4
+discusses.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -60,6 +66,84 @@ def _route(cfg: ModelConfig, logits: jnp.ndarray):
     return weights, experts, aux
 
 
+# Capacity dispatch and combine, per token group.  ``dest[c]`` is the slot
+# of flat choice ``c = t*k + j`` (token t's j-th expert), or the sentinel
+# ``E*cap`` where the choice was dropped; ``slot_choice[s]`` is the inverse,
+# the choice that fills slot s, or the sentinel ``ng*k`` where the slot is
+# empty.  A sentinel index is past the end of what it indexes: a gather
+# through it reads zeros.
+
+def _invert_slots(dest, n_slots):
+    """(ng*k,) slot of each choice -> (n_slots,) choice in each slot."""
+    n = dest.shape[0]
+    return jnp.full((n_slots,), n, jnp.int32).at[dest].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop")
+
+
+def _rows(a, idx):
+    """``a[idx]`` by rows, zeros where ``idx`` is past the end."""
+    return jnp.take(a, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _j_major(a, k):
+    """(ng*k, ...) in choice order -> (k, ng, ...): row j holds every
+    token's j-th choice, so a sum over j runs over the leading dim and the
+    rows keep the (ng, d) tiling (an (ng, k, d) array would pad k)."""
+    return jnp.swapaxes(a.reshape(-1, k, *a.shape[1:]), 0, 1)
+
+
+def _rows_by_choice(a, dest, k):
+    """(k, ng, d): the rows of ``a`` at each choice's slot, zeros for a
+    dropped choice."""
+    idx = _j_major(dest, k).reshape(-1)
+    return _rows(a, idx).reshape(k, -1, a.shape[-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, dest, slot_choice, k):
+    """(ng, d) tokens -> (E*cap, d) capacity buffer."""
+    return _rows(x, slot_choice // k)
+
+
+def _dispatch_fwd(x, dest, slot_choice, k):
+    return _dispatch(x, dest, slot_choice, k), dest
+
+
+def _dispatch_bwd(k, dest, dbuf):
+    # dx[t] = sum_j dbuf[dest[t, j]], a dropped choice adding nothing
+    g = _rows_by_choice(dbuf, dest, k).astype(jnp.float32)
+    return g.sum(0).astype(dbuf.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine(out_flat, w, dest, slot_choice, k):
+    """(E*cap, d) expert outputs -> (ng, d): token t gets the sum over its
+    kept choices of ``w[c] * out_flat[dest[c]]``."""
+    return _combine_fwd(out_flat, w, dest, slot_choice, k)[0]
+
+
+def _combine_fwd(out_flat, w, dest, slot_choice, k):
+    g = _rows_by_choice(out_flat, dest, k)                    # (k, ng, d)
+    y = (g.astype(jnp.float32) * _j_major(w, k)[..., None]).sum(0)
+    return y.astype(out_flat.dtype), (g, w, slot_choice)
+
+
+def _combine_bwd(k, res, dy):
+    g, w, slot_choice = res
+    # d out_flat[s] = w[c] * dy[c // k] for the choice c in slot s
+    d_out = (_rows(dy, slot_choice // k).astype(jnp.float32)
+             * _rows(w, slot_choice)[:, None]).astype(g.dtype)
+    # d w[c] = <dy[c // k], out_flat[dest[c]]>, 0 for a dropped choice
+    dw = (g.astype(jnp.float32) * dy.astype(jnp.float32)).sum(-1)
+    return d_out, dw.T.reshape(-1).astype(w.dtype), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def moe_ffn(params, cfg: ModelConfig, x, *,
             groups: Optional[int] = None,
             ep_axis: Optional[str] = None,
@@ -74,9 +158,16 @@ def moe_ffn(params, cfg: ModelConfig, x, *,
     scalars over this program's tokens (DESIGN.md §14).
 
     Tokens are grouped per data shard (per-group capacity — real
-    expert-parallel per-rank semantics).  The scatter/gather run under
-    ``vmap`` over the group dim, which makes G a scatter BATCH dimension the
-    SPMD partitioner can shard over the data axes; the expert einsums keep
+    expert-parallel per-rank semantics).  Within a group, choice
+    ``c = t*k + j`` (token t's j-th expert) keeps slot ``dest[c]`` of the
+    ``(E*cap, d)`` buffer if its expert has room; each filled slot holds
+    exactly one kept choice, ``slot_choice[s]``.  So dispatch gathers each
+    slot's token row, combine gathers each choice's output row and sums
+    the k weighted rows per token (in float32, rounded once), and their
+    backward passes gather too: ``dx`` through ``dest``, the buffer's
+    cotangent through ``slot_choice``.  The gathers run under ``vmap`` over
+    the group dim, which makes G a BATCH dimension the SPMD partitioner can
+    shard over the data axes; the expert einsums keep
     explicit (G, E, cap, ·) shapes with G over 'b' and E over 'model' — the
     expert-parallel all-to-all pattern of survey §4.
 
@@ -133,15 +224,12 @@ def moe_ffn(params, cfg: ModelConfig, x, *,
         aux = {"balance": balance,
                "dropped": jnp.sum(~keep).astype(jnp.float32),
                "routed": jnp.asarray(keep.size, jnp.float32)}
+        slot_choice = jax.vmap(
+            lambda ds: _invert_slots(ds, E * cap))(dest)         # (G, E*cap)
 
-        tok_idx = jnp.repeat(jnp.arange(ng), k)
         xg = constrain(xf.reshape(G, ng, d), ("b", None, None))
-        src = constrain(jnp.take(xg, tok_idx, axis=1), ("b", None, None))
-
-        def scatter_one(s, idx):
-            return jnp.zeros((E * cap + 1, d), cdt).at[idx].set(s)[: E * cap]
-
-        buf = jax.vmap(scatter_one)(src, dest)            # (G, E*cap, d)
+        buf = jax.vmap(lambda xs, ds, sc: _dispatch(xs, ds, sc, k))(
+            xg, dest, slot_choice)                                # (G, E*cap, d)
         buf = constrain(buf.reshape(G, E, cap, d), ("b", "m", None, None))
 
     with jax.named_scope("experts"):
@@ -175,18 +263,9 @@ def moe_ffn(params, cfg: ModelConfig, x, *,
                                  ("b", None, None))
 
     with jax.named_scope("combine"):
-        def gather_one(flat, idx, kp):
-            g = jnp.take(flat, jnp.minimum(idx, E * cap - 1), axis=0)
-            return jnp.where(kp[:, None], g, 0.0)
-
-        gathered = jax.vmap(gather_one)(out_flat, dest, keep)  # (G, ng*k, d)
-        contrib = gathered * wg[..., None].astype(gathered.dtype)
-
-        def combine_one(c):
-            return jnp.zeros((ng, d), cdt).at[tok_idx].add(c)
-
-        out = constrain(jax.vmap(combine_one)(contrib), ("b", None, None))
-        out = out.reshape(N, d)
+        out = jax.vmap(lambda of, ws, ds, sc: _combine(of, ws, ds, sc, k))(
+            out_flat, wg, dest, slot_choice)                      # (G, ng, d)
+        out = constrain(out, ("b", None, None)).reshape(N, d)
 
     if cfg.num_shared_experts:
         with jax.named_scope("shared"):
