@@ -9,9 +9,13 @@ reference's; for the first ``--faults`` seeds also the control (the
 reference with every matmul computed in int8 with one scale per tensor,
 the step below the configuration's bfloat16) and two planted faults, each
 compared with the float32 reference: half of the batch left out (the
-mean over the rest), and on several chips the exchange left out (chip
-0's rows alone).  A state left unchanged reads 1 on ``delta_gap`` by
-its definition and needs no run.  ``--look`` also traces two steps and
+mean over the rest), and on several chips, where the job syncs gradients,
+the exchange left out (chip 0's rows alone).  Each routes its data shards
+as the program would (``harness.reference_steps``).  A state left
+unchanged reads 1 on ``delta_gap`` by its definition and needs no run;
+on several chips each seed's ``replica_gap`` is read too (limit 0: the
+replicas must stay equal), and a replica left out of the exchange is a
+fault the CPU tests plant.  ``--look`` also traces two steps and
 writes what the trace holds.  Writes JSON lines to
 ``chiprun_out/calibrate/<workload>.jsonl``.  Not part of a benchmark
 run.
@@ -175,7 +179,8 @@ def main(argv=None) -> int:
         ref = harness.reference_steps(cell, params, seed, batches)
         rec = {"seed": seed, "prog_s": t_prog, "ref_s": time.time() - t,
                "prog_losses": prog["losses"], "ref_losses": ref["losses"],
-               "prog": check.readings(prog, ref)}
+               "prog": check.readings(prog, ref),
+               "replica_gap": prog.get("replica_gap")}
         if i < args.faults:
             ctrl = harness.reference_steps(cell, params, seed, batches,
                                            lowp=jax.numpy.int8)

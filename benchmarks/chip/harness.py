@@ -262,7 +262,31 @@ def first_steps(session, cell: Cell, params: Params, seed: int) -> Dict:
             g1 = [float(x) for x in g_norms(session._opt_state["m"])]
     d = params.delta_norms_fn()(session._params, params.key(seed))
     log(f"norms read at +{time.time() - t0:.1f}s")
-    return {"losses": losses, "g1": g1, "d3": [float(x) for x in d]}
+    out = {"losses": losses, "g1": g1, "d3": [float(x) for x in d]}
+    if cell.chips > 1:
+        out["replica_gap"] = float(replica_gap_fn(session.mesh, session.axes)(
+            session._params))
+    return out
+
+
+def replica_gap_fn(mesh, axes):
+    """jitted params -> the largest ``|x_d - x_0|`` of any parameter
+    between device 0's replica and device ``d``'s, over every device of
+    ``mesh``: replicated parameters that drift apart read above 0."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    def body(tree):
+        first = jax.lax.axis_index(axes) == 0
+        worst = jnp.zeros((), jnp.float32)
+        for x in jax.tree.leaves(tree):
+            x0 = jax.lax.psum(jnp.where(first, x, jnp.zeros_like(x)), axes)
+            worst = jnp.maximum(worst, jnp.max(jnp.abs(x - x0)).astype(
+                jnp.float32))
+        return jax.lax.pmax(worst, axes)
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False))
 
 
 def warm_up(session, counter: CompileCounter, limit: int = 4) -> int:
@@ -293,13 +317,18 @@ def memory_peak() -> int:
 def reference_steps(cell: Cell, params: Params, seed: int, batches,
                     rows: slice = slice(None), **kw) -> Dict:
     """The reference's first steps from the same weights and batches,
-    its rows split over the cell's chips where they divide."""
+    its rows split over the cell's chips where they divide.  The program
+    routes each data shard's rows alone (one chip's ``rows_per_chip``
+    rows), so the reference is told how many such shards the rows hold."""
+    import functools
     import jax
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from reference import train as rtrain
     module = importlib.import_module(f"reference.{cell.family}")
     n = len(range(*rows.indices(len(batches[0]["tokens"]))))
+    nll = functools.partial(module.nll_sum,
+                            shards=max(1, n // cell.job["rows_per_chip"]))
     sharding, place = None, None
     if cell.chips > 1 and n % cell.chips == 0:
         mesh = Mesh(np.array(jax.devices()), ("data",))
@@ -309,7 +338,7 @@ def reference_steps(cell: Cell, params: Params, seed: int, batches,
     def params0():
         p = params(seed)
         return p if place is None else jax.device_put(p, place)
-    return rtrain.run_steps(module.nll_sum, cell.config, params0, batches,
+    return rtrain.run_steps(nll, cell.config, params0, batches,
                             cell.job, data_sharding=sharding, rows=rows,
                             **kw)
 
@@ -354,11 +383,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     out: Dict[str, Any] = {}
     before = counter.n
     if not trace:
-        t0 = time.perf_counter()
-        while time.perf_counter() - t0 < seconds:
+        step_s = []
+        t0 = t1 = time.perf_counter()
+        while t1 - t0 < seconds:
             step_losses.append(session.step_once())
-        t1 = time.perf_counter()
+            t, t1 = t1, time.perf_counter()
+            step_s.append(t1 - t)
         tps = len(step_losses) * cell.tokens_per_step / (t1 - t0)
+        out["step_ms"] = step_quantiles(step_s)
         values = {"tokens_per_s": tps,
                   "mfu": 100.0 * tps * flops_tok / (
                       cell.chips * peaks["bf16_flops_per_s"]),
@@ -372,6 +404,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         step_losses, metrics, out = traced_window(
             session, cell, peaks, flops_tok, seconds)
     window_compiles = counter.n - before
+    out["session"] = {k: getattr(session, k, None)
+                      for k in ("compiles", "cache_hits", "compile_s")}
     device = dict(device, memory_peak_bytes=memory_peak(), **out.pop(
         "device", {}))
     free_program(session)
@@ -384,6 +418,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     found = check.readings(prog, ref)
     values = {k: v for k, (v, _) in found.items()}
     values["window_compiles"] = float(window_compiles)
+    if "replica_gap" in prog:
+        values["replica_gap"] = prog["replica_gap"]
     limits = dict(cell.limits["limits"], window_compiles=0.0)
     ok, rows = check.judge(values, limits)
     failed = sum(1 for x in step_losses if not math.isfinite(x))
@@ -401,6 +437,16 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     result["checks"] = {name: {"value": v, "limit": lim}
                         for name, v, lim in rows}
     return result
+
+
+def step_quantiles(step_s: List[float]) -> Dict[str, float]:
+    """The window's step times in ms: least, quartiles, most, and count
+    (a whole slow run moves every quantile, a few long steps the top)."""
+    import statistics
+    ms = sorted(1e3 * t for t in step_s)
+    q = statistics.quantiles(ms, n=4) if len(ms) > 1 else ms * 3
+    return {"min": ms[0], "q1": q[0], "median": q[1], "q3": q[2],
+            "max": ms[-1], "n": len(ms)}
 
 
 def traced_window(session, cell: Cell, peaks, flops_tok, seconds):

@@ -11,11 +11,14 @@ one JSON line: the session's compile counters, the device time by part
 ``<scope>:<instruction>``, the longest idle gaps named by the innermost
 host span (the program's ``repro.*`` spans and the harness's ``bench.*``),
 and the mean length of each ``repro.*`` span.  A second trace of
-``--keep`` steps is written, with the scope of each of its ops, to
-``chiprun_out/record/`` as ``trace_<config>-scoped.json.gz`` and
-``scopes_<config>.json.gz``: the recorded trace the CPU tests read.
+``--keep`` steps is written, with the scope of each of its ops, to the
+output directory's ``record/`` as ``trace_<cell>-scoped.json.gz``,
+``scopes_<cell>.json.gz`` and ``collectives_<cell>.json.gz`` (its
+instructions whose opcode is a collective): the recorded trace the CPU
+tests read.
 ``--hlo`` also writes each compiled step program's text there
-(``hlo_<config>.<program>.txt.gz``).
+(``hlo_<cell>.<program>.txt.gz``).  A step that syncs gradients also gets
+its ``grad_sync`` time per step by bucket, collectives apart.
 """
 from __future__ import annotations
 
@@ -25,6 +28,7 @@ import glob
 import gzip
 import json
 import os
+import re
 import shutil
 import sys
 import time
@@ -36,6 +40,7 @@ import harness  # noqa: E402
 import tracefile as tr  # noqa: E402
 
 PREFIXES = ("bench.", "repro.")
+BUCKET = re.compile(r"bucket_\d+")
 
 
 def host_spans(trace_dir):
@@ -79,16 +84,22 @@ def breakdown(session, tdir, steps):
     spans = host_spans(tdir)
     plane = tr.busiest(trace)
     lo, hi = tr.window(trace)
-    names = scopes.program_scopes(session) or {}
-    parts = scopes.split(trace, plane, names)
+    names, collectives = scopes.program_scopes(session) or ({}, set())
+    parts = scopes.split(trace, plane, names, collectives)
     per_step = {k: v / steps / 1e6 for k, v in parts.items()}
     tot = collections.Counter()
     inside = [(n, max(s, lo), min(e, hi)) for n, s, e in trace.devices[plane]
               if e > lo and s < hi]
+    buckets = collections.Counter()
     for name, t in tr.self_times(inside):
         op = names.get(name.split(":", 1)[-1])
         scope = "/".join(p for p in op.split("/")[1:]) if op else "?"
         tot[f"{scope}:{name}"] += t
+        bucket = BUCKET.search(scope)
+        if bucket and "grad_sync" in scope:
+            kind = ("collective" if name.split(":", 1)[-1] in collectives
+                    else "other")
+            buckets[f"{bucket.group(0)}:{kind}"] += t
     gaps = tr.subtract([(lo, hi)], tr.busy(trace, plane))
     named = collections.defaultdict(list)
     for s, e in gaps:
@@ -103,6 +114,8 @@ def breakdown(session, tdir, steps):
         "busy_ms": tr.total(tr.busy(trace, plane)) / 1e6,
         "per_step_ms": per_step,
         "top_ops_ms": [(k, v / 1e6) for k, v in tot.most_common(25)],
+        "grad_sync_by_bucket_ms": {k: v / steps / 1e6
+                                   for k, v in sorted(buckets.items())},
         "idle_by_span_ms": {k: {"n": len(v), "total": sum(v),
                                 "max": max(v)}
                             for k, v in sorted(named.items(),
@@ -112,7 +125,7 @@ def breakdown(session, tdir, steps):
                                   key=lambda g: -g[1])[:12],
         "repro_span_mean_ms": {k: sum(v) / len(v)
                                for k, v in sorted(lengths.items())},
-    }, trace, names
+    }, trace, (names, collectives)
 
 
 def main(argv=None) -> int:
@@ -145,20 +158,23 @@ def main(argv=None) -> int:
     trace_steps(session, args.steps, tdir)
     out["breakdown"], _, _ = breakdown(session, tdir, args.steps)
     trace_steps(session, args.keep, tdir)
-    _, trace, names = breakdown(session, tdir, args.keep)
+    _, trace, (names, collectives) = breakdown(session, tdir, args.keep)
     dest = os.path.join(harness.ROOT, "chiprun_out", "record")
     os.makedirs(dest, exist_ok=True)
-    config = cell.name.split(".", 1)[0]
-    tr.save_json(trace, os.path.join(dest, f"trace_{config}-scoped.json.gz"))
+    tr.save_json(trace, os.path.join(dest,
+                                     f"trace_{cell.name}-scoped.json.gz"))
     used = {n.split(":", 1)[-1] for ops in trace.devices.values()
             for n, _, _ in ops}
-    with gzip.open(os.path.join(dest, f"scopes_{config}.json.gz"),
+    with gzip.open(os.path.join(dest, f"scopes_{cell.name}.json.gz"),
                    "wt") as f:
         json.dump({k: v for k, v in names.items() if k in used}, f)
+    with gzip.open(os.path.join(dest, f"collectives_{cell.name}.json.gz"),
+                   "wt") as f:
+        json.dump(sorted(collectives & used), f)
     if args.hlo:
         for name, compiled in getattr(session, "programs", {}).items():
-            with gzip.open(os.path.join(dest, f"hlo_{config}.{name}.txt.gz"),
-                           "wt") as f:
+            with gzip.open(os.path.join(
+                    dest, f"hlo_{cell.name}.{name}.txt.gz"), "wt") as f:
                 f.write(compiled.as_text())
     shutil.rmtree(tdir, ignore_errors=True)
     print(json.dumps(out), flush=True)

@@ -14,11 +14,14 @@ Departures from the published model, all shared with the configuration
 as run (and listed in the benchmark's PERF.md):
   * the top-k weights are renormalised to sum to 1 (published:
     ``norm_topk_prob`` false);
-  * an expert keeps at most ``capacity_factor * tokens * k / E`` of its
-    token choices, in token order, over the whole batch, and drops the
-    rest (published: device-level dropping);
+  * the batch's rows split into ``shards`` equal groups, one per data
+    shard (device ``d`` holds rows ``[d R, (d+1) R)``), and each group
+    routes alone: an expert keeps at most ``capacity_factor * tokens * k
+    / E`` of the group's token choices, in token order, and drops the
+    rest (published: device-level dropping at a factor not given);
   * the balance loss is ``aux_coef * E * sum_e f_e p_e`` over the top-1
-    choices (published: expert-, device- and communication-level losses);
+    choices of each group, averaged over the groups (published: expert-,
+    device- and communication-level losses);
   * rotary embedding in the halves layout without YaRN scaling or its
     attention-scale correction (published: YaRN, factor 40);
   * norms store their scale as a delta on 1.
@@ -89,20 +92,28 @@ def _swiglu(p, x):
                 p["wo"])
 
 
-def _moe(p, s, x):
-    """Returns (output, balance loss) for x (B, T, d)."""
+def _balance(probs, top1, E):
+    """Switch-style balance ``E * sum_e f_e p_e`` of one group's tokens."""
+    return E * jnp.sum(jax.nn.one_hot(top1, E).mean(0) * probs.mean(0))
+
+
+def _moe(p, s, x, shards=1):
+    """Returns (output, balance loss) for x (B, T, d), the B rows routed
+    in ``shards`` equal groups of consecutive rows."""
     B, T, d = x.shape
     E, k = s["n_routed_experts"], s["num_experts_per_tok"]
     n = B * T
+    ng = n // shards
     xf = x.reshape(n, d)
     probs = jax.nn.softmax(C.mm(xf, p["router"]), axis=-1)         # (n, E)
     w, ex = jax.lax.top_k(probs, k)
     w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
-    aux = E * jnp.sum(jax.nn.one_hot(ex[:, 0], E).mean(0) * probs.mean(0))
-    # capacity: choices claim slots of their expert in token order
-    cap = int(max(1, n * k / E * s["capacity_factor"]))
-    hot = jax.nn.one_hot(ex.reshape(-1), E, dtype=jnp.int32)        # (n*k, E)
-    slot = jnp.sum((jnp.cumsum(hot, axis=0) - 1) * hot, axis=-1)
+    aux = sum(_balance(probs[g * ng:(g + 1) * ng], ex[g * ng:(g + 1) * ng, 0],
+                       E) for g in range(shards)) / shards
+    # capacity: a group's choices claim slots of their expert in token order
+    cap = int(max(1, ng * k / E * s["capacity_factor"]))
+    hot = jax.nn.one_hot(ex.reshape(shards, ng * k), E, dtype=jnp.int32)
+    slot = jnp.sum((jnp.cumsum(hot, axis=1) - 1) * hot, axis=-1)  # (S, ng*k)
     keep = (slot < cap).reshape(n, k)
     comb = jnp.einsum("nk,nke->ne", w * keep,
                       jax.nn.one_hot(ex, E, dtype=jnp.float32))      # (n, E)
@@ -120,8 +131,9 @@ def _moe(p, s, x):
     return out.reshape(B, T, d), aux
 
 
-def nll_sum(params, sizes, tokens):
-    """(summed next-token NLL, predicted positions, weighted balance loss)."""
+def nll_sum(params, sizes, tokens, shards=1):
+    """(summed next-token NLL, predicted positions, weighted balance loss),
+    the MoE layers routing ``shards`` equal groups of rows alone."""
     eps = sizes["rms_norm_eps"]
     x = jnp.take(params["embed"]["table"], tokens, axis=0)
     aux = jnp.zeros((), jnp.float32)
@@ -133,7 +145,8 @@ def nll_sum(params, sizes, tokens):
         if kind == "dense":
             x = x + jax.checkpoint(_swiglu)(p["ffn"], h)
         else:
-            y, a = jax.checkpoint(lambda q, h_: _moe(q, sizes, h_))(p["ffn"], h)
+            y, a = jax.checkpoint(lambda q, h_: _moe(q, sizes, h_, shards))(
+                p["ffn"], h)
             x, aux = x + y, aux + a
     h = C.rmsnorm(x, params["final_norm"]["scale"], eps)
     total, count = C.next_token_nll(h, params["lm_head"]["table"], tokens)

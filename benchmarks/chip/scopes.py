@@ -19,7 +19,7 @@ counts under the step's scope: a few microseconds a step.
 from __future__ import annotations
 
 import re
-from typing import Dict, Optional, Set
+from typing import Dict, Optional, Set, Tuple
 
 import tracefile as tr
 
@@ -38,7 +38,13 @@ PARTS = {
     "optimizer": lambda n, s: "optimizer" in s,
     "attn": lambda n, s: "attn" in s,
     "moe": lambda n, s: "moe" in s,
+    "grad_sync": lambda n, s: "grad_sync" in s,
 }
+# the opcodes of collectives, with their async ``-start``/``-done`` halves
+# (an instruction's name need not say it: ``psum.229 = ... all-reduce(``)
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+OPCODE = re.compile(r"\s([\w\-]+)\(")
 
 
 def scope_map(text: str) -> Dict[str, str]:
@@ -105,6 +111,19 @@ def scope_map(text: str) -> Dict[str, str]:
     return out
 
 
+def collective_names(text: str) -> Set[str]:
+    """Names of the instructions of a compiled HLO module's text whose
+    opcode is a collective."""
+    out = set()
+    for line in text.splitlines():
+        m = INSTRUCTION.match(line)
+        if m:
+            op = OPCODE.search(" " + line[m.end():].split(" metadata=")[0])
+            if op and op.group(1).startswith(COLLECTIVES):
+                out.add(m.group(1))
+    return out
+
+
 def scope_names(op_name: str) -> Set[str]:
     """The scope names on an op's name stack, with JAX's transform
     wrappers taken off (``jvp(embed)`` and ``transpose(jvp(head))`` give
@@ -119,31 +138,39 @@ def scope_names(op_name: str) -> Set[str]:
     return out
 
 
-def program_scopes(session) -> Optional[Dict[str, str]]:
-    """The scope map of every step program the session has compiled, or
-    None when it keeps none or none of its ops carries a scope."""
+def program_scopes(session) -> Optional[Tuple[Dict[str, str], Set[str]]]:
+    """The scope map and the collectives of every step program the session
+    has compiled, or None when it keeps none or none of its ops carries a
+    scope."""
     programs = getattr(session, "programs", None)
     if not programs:
         return None
     scopes: Dict[str, str] = {}
+    collectives: Set[str] = set()
     for compiled in programs.values():
-        scopes.update(scope_map(compiled.as_text()))
+        text = compiled.as_text()
+        scopes.update(scope_map(text))
+        collectives |= collective_names(text)
     if not any("forward" in scope_names(n) for n in scopes.values()):
         return None
-    return scopes
+    return scopes, collectives
 
 
-def split(trace, plane: str, scopes: Dict[str, str]) -> Dict[str, int]:
+def split(trace, plane: str, scopes: Dict[str, str],
+          collectives: Set[str] = frozenset()) -> Dict[str, int]:
     """Self time (ns) of the ops inside the window on ``plane``, summed
-    by part (``PARTS``), with ``busy`` (all of it) and ``unscoped``."""
+    by part (``PARTS``), with ``busy`` (all of it), ``unscoped``, and
+    ``grad_sync_collectives`` (the ``collectives`` under ``grad_sync``)."""
     lo, hi = tr.window(trace)
     inside = [(n, max(s, lo), min(e, hi)) for n, s, e in trace.devices[plane]
               if e > lo and s < hi]
-    out = dict.fromkeys(list(PARTS) + ["busy", "unscoped"], 0)
+    out = dict.fromkeys(list(PARTS) + ["busy", "unscoped",
+                                       "grad_sync_collectives"], 0)
     for name, t in tr.self_times(inside):
         out["busy"] += t
-        op = scopes.get(name[len(tr.KERNEL_PREFIX):]
-                        if name.startswith(tr.KERNEL_PREFIX) else name)
+        if name.startswith(tr.KERNEL_PREFIX):
+            name = name[len(tr.KERNEL_PREFIX):]
+        op = scopes.get(name)
         if op is None:
             out["unscoped"] += t
             continue
@@ -151,6 +178,8 @@ def split(trace, plane: str, scopes: Dict[str, str]) -> Dict[str, int]:
         for part, test in PARTS.items():
             if test(op, names):
                 out[part] += t
+        if "grad_sync" in names and name in collectives:
+            out["grad_sync_collectives"] += t
     return out
 
 
@@ -161,9 +190,9 @@ def per_step_ms(ctx, part: str) -> Optional[float]:
     """A part's device self time per traced step, in ms, on the busiest
     device; None where the program gives no scopes."""
     if _LAST[0] is not ctx.trace:
-        scopes = program_scopes(ctx.session)
-        _LAST[:] = [ctx.trace, None if scopes is None else split(
-            ctx.trace, tr.busiest(ctx.trace), scopes)]
+        found = program_scopes(ctx.session)
+        _LAST[:] = [ctx.trace, None if found is None else split(
+            ctx.trace, tr.busiest(ctx.trace), *found)]
     got = _LAST[1]
     if got is None or not ctx.steps:
         return None

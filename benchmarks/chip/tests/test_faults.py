@@ -24,19 +24,25 @@ def ds_cell():
                                              seq_len=64), LIMITS, f32=True)
 
 
-def ds_dp4_cell():
-    """Four virtual devices, the gradient exchanged by the job's sync.  Each
-    device routes its own rows, so expert capacity and the balance loss
-    would differ from the reference's over the whole batch: this cell has
-    capacity for every choice and no balance loss."""
-    config = dict(tiny.DEEPSEEK, capacity_factor=4.0, aux_coef=0.0,
-                  overrides=dict(tiny.DEEPSEEK["overrides"],
-                                 capacity_factor=4.0, router_aux_coef=0.0))
-    sync = {"scheduler": "every_step",
-            "config": {"compressor": "none", "algo": "psum",
-                       "error_feedback": False}}
-    return tiny.cell(config, tiny.job("train-4k", chips=4, rows_per_chip=2,
-                                      seq_len=64, sync=sync), LIMITS, f32=True)
+SYNCS = {
+    "psum": {"compressor": "none", "algo": "psum", "error_feedback": False},
+    "int8_fused-ring": {"compressor": "int8_fused", "algo": "ring",
+                        "bucket_bytes": 32 * 1024, "error_feedback": True},
+}
+DP4_LIMITS = dict(LIMITS, replica_gap=0.0)
+
+
+def ds_dp4_cell(wire="psum"):
+    """Four virtual devices, the gradient exchanged by the job's sync, at
+    the configuration's own capacity factor and balance loss.  Each device
+    routes its own rows, and at these sizes some choices overflow their
+    expert's capacity, so the reference has to route each device's rows
+    alone to agree."""
+    sync = {"scheduler": "every_step", "config": SYNCS[wire]}
+    return tiny.cell(tiny.DEEPSEEK, tiny.job("train-4k", chips=4,
+                                             rows_per_chip=2, seq_len=64,
+                                             sync=sync),
+                     DP4_LIMITS, f32=True)
 
 
 def run(cell, tamper=None):
@@ -45,39 +51,38 @@ def run(cell, tamper=None):
                        tamper=tamper)
 
 
-def _after_build(session, wrap):
+def _wrap_step(session, make):
+    """Once the session is built, replace its step program (``_base``, or
+    ``_sync`` where the job syncs gradients) with ``make(program)``."""
     build = session._build
 
     def patched():
         if not session._built:
             build()
-            wrap(session)
+            name = "_base" if session.strategy is None else "_sync"
+            setattr(session, name, make(getattr(session, name)))
     session._build = patched
 
 
 def unchanged_state(session):
     """A step that returns its state unchanged."""
-    def wrap(s):
-        f = s._base
-
-        def step(p, o, b, i):
-            loss = f(jax.tree.map(jnp.copy, p), jax.tree.map(jnp.copy, o),
-                     b, i)[2]
-            return p, o, loss
-        s._base = step
-    _after_build(session, wrap)
+    def make(f):
+        def step(*args):
+            out = f(*(jax.tree.map(jnp.copy, a) for a in args))
+            return (*args[:len(out) - 1], out[-1])
+        return step
+    _wrap_step(session, make)
 
 
 def half_batch(session):
     """Half of the batch left out, the mean taken over the rest."""
-    def wrap(s):
-        f = s._base
-
-        def step(p, o, b, i):
-            n = b["tokens"].shape[0] // 2
-            return f(p, o, {"tokens": b["tokens"][:n]}, i)
-        s._base = step
-    _after_build(session, wrap)
+    def make(f):
+        def step(*args):
+            return f(*({"tokens": a["tokens"][:a["tokens"].shape[0] // 2]}
+                       if isinstance(a, dict) and "tokens" in a else a
+                       for a in args))
+        return step
+    _wrap_step(session, make)
 
 
 class NoExchange:
@@ -96,6 +101,30 @@ def no_exchange(session):
                                     grad_reducer=NoExchange())
 
 
+class LeftOut:
+    """The exchange with the last replica left out of it: that device
+    keeps its own gradient, the others get the synced one."""
+
+    def __init__(self, reducer):
+        self.reducer = reducer
+
+    def init_state(self, grads):
+        return self.reducer.init_state(grads)
+
+    def __call__(self, grads, state, rng):
+        synced, state = self.reducer(grads, state, rng)
+        last = jax.lax.axis_index("data") == jax.lax.axis_size("data") - 1
+        return jax.tree.map(lambda s, g: jnp.where(last, g.astype(s.dtype), s),
+                            synced, grads), state
+
+
+def replica_left_out(session):
+    from repro.core import SyncStrategy
+    session.strategy = SyncStrategy(
+        scheduler=session.strategy.scheduler,
+        grad_reducer=LeftOut(session.strategy.grad_reducer))
+
+
 def test_sound_program_is_correct():
     r = run(ds_cell())
     assert r["correct"], r["checks"]
@@ -109,10 +138,21 @@ def test_fault_is_not_correct(fault):
     assert not r["correct"], r["checks"]
 
 
-def test_sound_exchange_is_correct_and_no_exchange_is_not():
-    cell = ds_dp4_cell()
-    assert run(cell)["correct"]
-    r = run(cell, tamper=no_exchange)
+@pytest.mark.parametrize("wire", sorted(SYNCS))
+def test_sound_exchange_is_correct_and_drops_choices(wire):
+    sessions = []
+    r = run(ds_dp4_cell(wire), tamper=sessions.append)
+    assert r["correct"], r["checks"]
+    assert r["checks"]["replica_gap"]["value"] == 0.0
+    assert sessions[0].dropped_tokens > 0
+
+
+@pytest.mark.parametrize("fault", [no_exchange, replica_left_out,
+                                   unchanged_state, half_batch],
+                         ids=["no_exchange", "replica_left_out",
+                              "unchanged_state", "half_batch"])
+def test_exchange_fault_is_not_correct(fault):
+    r = run(ds_dp4_cell(), tamper=fault)
     assert not r["correct"], r["checks"]
 
 

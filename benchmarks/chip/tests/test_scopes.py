@@ -1,7 +1,8 @@
 """Device time by the program's named scopes (``scopes.py`` and the readers
 ``fwd_ms``, ``bwd_ms``, ``opt_ms``, ``attn_ms``, ``moe_ms``,
-``step_compile_s``), on hand-made HLO and traces with answers worked out by
-hand, on a real session's compiled step, and on the recorded traces."""
+``step_compile_s``, ``grad_sync_ms``, ``collective_exposed_ms``), on
+hand-made HLO and traces with answers worked out by hand, on real sessions'
+compiled steps, and on the recorded traces."""
 import glob
 import gzip
 import importlib
@@ -156,11 +157,75 @@ def test_a_real_step_program_is_covered_by_its_scopes():
     for op in m.values():
         for p, test in scopes.PARTS.items():
             parts[p] += test(op, scopes.scope_names(op))
-    assert all(parts.values()), parts
+    # one device syncs no gradient: every part but grad_sync has ops
+    assert parts.pop("grad_sync") == 0 and all(parts.values()), parts
     fusions = [l.split(" = ")[0].strip().lstrip("%").replace("ROOT ", "")
                for l in text.splitlines() if " fusion(" in l]
     named = sum(f.lstrip("%") in m for f in fusions)
     assert fusions and named >= 0.85 * len(fusions), (named, len(fusions))
+
+
+# A synced step: a backward fusion, then the gradient exchange under
+# grad_sync (a synchronous all-reduce that the compiler named after its
+# JAX primitive, an async all-gather's start and done,
+# a fusion that divides by the world size), the loss's pmean, which is a
+# collective outside grad_sync, and the optimizer.
+SYNC_HLO = """\
+HloModule jit_step_fn, is_scheduled=true
+
+ENTRY %main.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %fusion.2 = f32[8]{0} fusion(%p), kind=kLoop, metadata={op_name="jit(step_fn)/shard_map/forward/transpose(jvp())/mul"}
+  %psum.3 = f32[8]{0} all-reduce(%fusion.2), to_apply=%sum, metadata={op_name="jit(step_fn)/shard_map/grad_sync/bucket_0/psum"}
+  %all-gather-start.4 = s8[4,8]{1,0} all-gather-start(%p), metadata={op_name="jit(step_fn)/shard_map/grad_sync/bucket_1/all_gather"}
+  %all-gather-done.5 = s8[4,8]{1,0} all-gather-done(%all-gather-start.4), metadata={op_name="jit(step_fn)/shard_map/grad_sync/bucket_1/all_gather"}
+  %fusion.6 = f32[8]{0} fusion(%psum.3), kind=kLoop, metadata={op_name="jit(step_fn)/shard_map/grad_sync/bucket_0/div"}
+  %all-reduce.7 = f32[] all-reduce(%p), to_apply=%sum, metadata={op_name="jit(step_fn)/shard_map/pmean"}
+  ROOT %fusion.8 = f32[8]{0} fusion(%fusion.6), kind=kLoop, metadata={op_name="jit(step_fn)/shard_map/optimizer/sub"}
+}
+"""
+SYNC_ONE = [("fusion.2", 0, 40), ("psum.3", 40, 70),
+            ("all-gather-start.4", 70, 72), ("all-gather-done.5", 72, 80),
+            ("fusion.6", 80, 85), ("all-reduce.7", 85, 90),
+            ("fusion.8", 90, 100)]
+SYNC_TRACE = tr.Trace(
+    {DEV: [(n, s + k, e + k) for k in (1000, 1300) for n, s, e in SYNC_ONE]},
+    [("bench.window", 990, 1410), ("bench.step", 995, 1200),
+     ("bench.step", 1290, 1405)])
+
+
+def test_grad_sync_readers_on_a_hand_made_trace():
+    """Per step: grad_sync = psum 30 + all-gather start 2 and done 8
+    + the division's fusion 5 = 45 ns, of which the collectives are 40; the
+    loss's pmean (5 ns) is no gradient exchange.  A step without grad_sync
+    gives neither reading."""
+    c = ctx(SYNC_TRACE, types.SimpleNamespace(
+        programs={"sync": Compiled(SYNC_HLO)}))
+    assert read("grad_sync_ms", c) == pytest.approx(45e-6)
+    assert read("collective_exposed_ms", c) == pytest.approx(40e-6)
+    assert read("bwd_ms", c) == pytest.approx(40e-6)
+    plain = ctx(tr.Trace(dict(HAND.devices), list(HAND.spans)),
+                types.SimpleNamespace(programs={"base": Compiled(HLO)}))
+    assert read("grad_sync_ms", plain) is None
+    assert read("collective_exposed_ms", plain) is None
+
+
+def test_a_real_synced_step_names_its_exchange():
+    """The compiled step of a tiny DeepSeek session on four devices with a
+    psum sync: its all-reduces of the gradients lie under grad_sync."""
+    import harness
+    import tiny
+    sync = {"scheduler": "every_step", "config": {
+        "compressor": "none", "algo": "psum", "error_feedback": False}}
+    cell = tiny.cell(tiny.DEEPSEEK, tiny.job(
+        "train-4k", chips=4, rows_per_chip=2, seq_len=32, sync=sync))
+    session = harness.build_session(cell, 7, harness.Params(cell))
+    session.step_once()
+    text = session.programs["sync"].as_text()
+    m = scopes.scope_map(text)
+    synced = [i for i in scopes.collective_names(text)
+              if "grad_sync" in scopes.scope_names(m.get(i, ""))]
+    assert synced, sorted(scopes.collective_names(text))
 
 
 RECORDED = sorted(glob.glob(os.path.join(os.path.dirname(__file__),
@@ -169,14 +234,20 @@ RECORDED = sorted(glob.glob(os.path.join(os.path.dirname(__file__),
 
 def _recorded_session(path):
     """A session stand-in whose compiled step is rebuilt from the scope
-    map recorded beside the trace (one instruction per line)."""
+    map recorded beside the trace (one instruction per line), with the
+    opcode ``all-reduce`` for the instructions listed as collectives."""
     name = os.path.basename(path)[len("trace_"):-len("-scoped.json.gz")]
-    with gzip.open(os.path.join(os.path.dirname(path),
-                                f"scopes_{name}.json.gz"), "rt") as f:
+    here = os.path.dirname(path)
+    with gzip.open(os.path.join(here, f"scopes_{name}.json.gz"), "rt") as f:
         m = json.load(f)
+    collectives = set()
+    if os.path.exists(os.path.join(here, f"collectives_{name}.json.gz")):
+        with gzip.open(os.path.join(here, f"collectives_{name}.json.gz"),
+                       "rt") as f:
+            collectives = set(json.load(f))
     text = "ENTRY %main (p: f32[]) -> f32[] {\n" + "".join(
-        f'  %{k} = f32[] add(), metadata={{op_name="{v}"}}\n'
-        for k, v in m.items()) + "}\n"
+        f'  %{k} = f32[] {"all-reduce" if k in collectives else "add"}(), '
+        f'metadata={{op_name="{v}"}}\n' for k, v in m.items()) + "}\n"
     return types.SimpleNamespace(programs={"base": Compiled(text)})
 
 
@@ -184,15 +255,16 @@ def _recorded_session(path):
                          ids=[os.path.basename(p) for p in RECORDED])
 def test_recorded_scoped_trace(path):
     """Two steps of the cell on the chip, with its scopes: forward,
-    backward and optimizer cover at least 95% of the busy time, attention
-    and MoE lie inside forward plus backward, and the backward is longer
-    than the forward."""
+    backward, optimizer and gradient sync cover at least 95% of the busy
+    time, attention and MoE lie inside forward plus backward, and the
+    backward is longer than the forward."""
     t = tr.load_json(path)
     c = ctx(t, _recorded_session(path))
-    got = {n: read(n, c) for n in READERS}
+    got = {n: read(n, c) for n in READERS + ("grad_sync_ms",)}
     plane = tr.busiest(t)
     busy = tr.total(tr.busy(t, plane)) / 2 / 1e6
-    assert got["fwd_ms"] + got["bwd_ms"] + got["opt_ms"] >= 0.95 * busy
+    assert got["fwd_ms"] + got["bwd_ms"] + got["opt_ms"] + (
+        got["grad_sync_ms"] or 0.0) >= 0.95 * busy
     assert got["attn_ms"] + got["moe_ms"] < got["fwd_ms"] + got["bwd_ms"]
     assert got["bwd_ms"] > got["fwd_ms"] > 0 and got["opt_ms"] > 0
 
@@ -218,3 +290,28 @@ def test_old_recorded_trace_reads_as_before():
         100 * 1.425e9 * 8192 / 0.240381156 / 197e12)
     for name in READERS + ("step_compile_s",):
         assert read(name, c) is None
+
+
+DP4 = os.path.join(os.path.dirname(__file__),
+                   "trace_deepseek-v2-lite-2l.dp4-psum.4chip-scoped.json.gz")
+
+
+def test_recorded_four_chip_trace_reads_its_exchange():
+    """Two steps of the four-chip psum cell on TPU v5 lite chips (seed
+    3415100003).  The collectives listed beside it are the trace's
+    instructions whose opcode is ``all-reduce`` in the compiled step (of
+    the same program compiled for a described v5e:2x2: every scoped
+    instruction of the trace has the same name and scope there); five of
+    them are named ``psum.<n>``.  The exchange is all of the gradient
+    sync's time (its converts and the division fuse into the backward and
+    the optimizer), at most the busy step; the readings are those of the
+    recording."""
+    t = tr.load_json(DP4)
+    c = ctx(t, _recorded_session(DP4))
+    sync, exposed = read("grad_sync_ms", c), read("collective_exposed_ms", c)
+    busy = tr.total(tr.busy(t, tr.busiest(t))) / 2 / 1e6
+    assert exposed <= sync <= busy
+    assert sync == pytest.approx(54.090723)
+    assert exposed == pytest.approx(54.090723)
+    assert busy == pytest.approx(242.765954)
+    assert len(t.devices) == 4
