@@ -19,6 +19,7 @@ _ARCH_MODULES = {
     "xlstm-125m": "xlstm_125m",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
     "jamba-v0.1-52b": "jamba_v01_52b",
+    "kimi-linear-48b-a3b": "kimi_linear_48b_a3b",
 }
 
 ALL_ARCHS = tuple(_ARCH_MODULES)

@@ -18,7 +18,7 @@ from typing import Optional, Sequence, Tuple
 @dataclass(frozen=True)
 class LayerSpec:
     """One layer of the stack: a (token-)mixer plus an optional FFN."""
-    mixer: str = "attn"           # attn | mla | mamba | slstm | mlstm
+    mixer: str = "attn"           # attn | mla | mamba | kda | slstm | mlstm
     window: Optional[int] = None  # sliding-window size; None = global attention
     ffn: str = "dense"            # dense | moe | none
 
@@ -90,6 +90,10 @@ class ModelConfig:
     first_dense: int = 0              # first N layers use dense FFN (deepseek-v2)
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    router_act: str = "softmax"       # softmax | sigmoid (kimi-linear)
+    routed_scale: float = 1.0         # routed output x this (kimi: 2.446)
+    experts_held: int = 0             # experts this chip holds; 0 = all
+    experts_offset: int = 0           # index of the first one held
 
     # MLA (deepseek-v2)
     use_mla: bool = False
@@ -97,14 +101,18 @@ class ModelConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+    mla_rope: bool = True             # False: no rotary on q/k (kimi NoPE MLA)
 
     # SSM / hybrid
     ssm_d_state: int = 16
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = 0              # 0 -> ceil(d_model/16)
-    attn_every: int = 0               # jamba: attention layer every k-th (else mamba)
+    attn_every: int = 0               # jamba: attention layer every k-th (else linear_mixer)
     attn_offset: int = 0              # index within period that is attention
+    attn_extra: Tuple[int, ...] = ()  # further attention layers (kimi: the last)
+    linear_mixer: str = "mamba"       # mixer of the other layers: mamba | kda
+                                      # (kda: num_heads of head_dim, conv ssm_conv)
 
     # xLSTM
     slstm_every: int = 0              # sLSTM every k-th layer (else mLSTM)
@@ -145,8 +153,10 @@ class ModelConfig:
         if self.family == "ssm":  # xLSTM
             mixer = "slstm" if (self.slstm_every and i % self.slstm_every == self.slstm_every - 1) else "mlstm"
             return LayerSpec(mixer=mixer, ffn="none")
-        if self.attn_every:  # hybrid (jamba)
-            mixer = "attn" if i % self.attn_every == self.attn_offset else "mamba"
+        if self.attn_every:  # hybrid (jamba, kimi-linear)
+            full = i % self.attn_every == self.attn_offset or i in self.attn_extra
+            mixer = (("mla" if self.use_mla else "attn") if full
+                     else self.linear_mixer)
         elif self.use_mla:
             mixer = "mla"
         else:
@@ -177,6 +187,9 @@ class ModelConfig:
         if rest:
             p = period
             reps, rem = divmod(len(rest), p)
+            if any(rest[r * p:(r + 1) * p] != rest[:p] for r in range(reps)):
+                raise ValueError(f"{self.name}: layers {i}.. do not repeat "
+                                 f"with period {p}")
             if reps:
                 segs.append(Segment(tuple(rest[:p]), reps))
             if rem:
@@ -228,8 +241,8 @@ def reduced(cfg: ModelConfig, seq_cap: int = 128) -> ModelConfig:
                    num_shared_experts=min(cfg.num_shared_experts, 1))
     if cfg.use_mla:
         upd.update(kv_lora_rank=64, qk_nope_dim=32, qk_rope_dim=16, v_head_dim=32)
-    if cfg.attn_every:
-        upd.update(num_layers=cfg.attn_every)  # one full hybrid period
+    if cfg.attn_every:  # one full hybrid period after the leading dense layers
+        upd.update(num_layers=cfg.first_dense + cfg.attn_every)
     if cfg.is_encoder_decoder:
         upd.update(num_encoder_layers=2)
     if cfg.family == "ssm":

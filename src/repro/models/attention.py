@@ -391,11 +391,15 @@ def _mla_qkv(params, cfg: ModelConfig, x, positions):
     nope, rope, vdim = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     q = (x @ params["wq"]).reshape(B, T, H, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    # kimi-linear's MLA (mla_rope False) keeps the split, without rotation
+    if cfg.mla_rope:
+        q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
 
     latent = x @ params["w_dkv"]
     c_kv = rmsnorm(params["kv_norm"], latent[..., :cfg.kv_lora_rank], eps=cfg.norm_eps)
-    k_rope = apply_rope(latent[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)
+    k_rope = latent[..., None, cfg.kv_lora_rank:]
+    if cfg.mla_rope:
+        k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
     return q_nope, q_rope, c_kv, k_rope
 
 

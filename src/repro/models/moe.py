@@ -38,11 +38,13 @@ def add_aux(a, b):
 
 
 def moe_desc(cfg: ModelConfig) -> Dict[str, ParamDesc]:
+    """The router scores all ``num_experts``; the expert weights are the
+    ``experts_held`` this layer holds (all when 0)."""
     d = cfg.d_model
     ff = cfg.moe_d_ff or cfg.d_ff
-    E = cfg.num_experts
+    E = cfg.experts_held or cfg.num_experts
     desc = {
-        "router": ParamDesc((d, E), ("embed", None), "small"),
+        "router": ParamDesc((d, cfg.num_experts), ("embed", None), "small"),
         "wi_gate": ParamDesc((E, d, ff), ("experts", "embed", "ffn")),
         "wi_up": ParamDesc((E, d, ff), ("experts", "embed", "ffn")),
         "wo": ParamDesc((E, ff, d), ("experts", "ffn", "embed")),
@@ -53,10 +55,21 @@ def moe_desc(cfg: ModelConfig) -> Dict[str, ParamDesc]:
 
 
 def _route(cfg: ModelConfig, logits: jnp.ndarray):
-    """logits: (N, E) -> (weights (N,k), experts (N,k), aux_loss)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, experts = jax.lax.top_k(probs, cfg.top_k)
+    """logits: (N, E) -> (weights (N,k), experts (N,k), aux_loss).
+
+    The top k experts by score (softmax, or kimi-linear's sigmoid), their
+    weights renormalised to sum to 1, then scaled by ``routed_scale``.
+    The balance loss reads the scores normalised over the E experts."""
+    if cfg.router_act == "sigmoid":
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        weights, experts = jax.lax.top_k(scores, cfg.top_k)
+        probs = scores / scores.sum(-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        weights, experts = jax.lax.top_k(probs, cfg.top_k)
     weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    if cfg.routed_scale != 1.0:
+        weights = weights * cfg.routed_scale
     # switch-style load balance: E * sum_e f_e * p_e
     E = logits.shape[-1]
     one_hot = jax.nn.one_hot(experts[..., 0], E, dtype=jnp.float32)
@@ -174,6 +187,13 @@ def moe_ffn(params, cfg: ModelConfig, x, *,
     ``groups`` overrides the context-derived group count (the conformance
     checks use it to mirror an ep group's source batching on one device).
 
+    With ``cfg.experts_held`` set the layer holds that share of the
+    experts, from index ``cfg.experts_offset`` (the chip's share of an
+    expert-parallel deployment, guide §4): routing and the capacity run
+    over all ``num_experts`` as in the whole layer, a choice of an expert
+    held elsewhere takes no slot, and ``routed``/``dropped`` count the
+    choices of the held experts.  Nothing stands in for the other shares.
+
     ``ep_axis`` names a manual shard_map axis carrying TRUE expert
     parallelism (DESIGN.md §14): ``params`` hold only this rank's
     ``E/ep`` expert block (router replicated, routing still over global
@@ -193,7 +213,10 @@ def moe_ffn(params, cfg: ModelConfig, x, *,
         G = 1
     ng = N // G
     cap = int(max(1, ng * k / E * cfg.capacity_factor))
+    El = cfg.experts_held or E          # experts held here
     ep = 1
+    if ep_axis is not None and El != E:
+        raise ValueError("expert parallelism over a held share of experts")
     if ep_axis is not None:
         ep = jax.lax.axis_size(ep_axis)
         if G != 1:
@@ -215,22 +238,29 @@ def moe_ffn(params, cfg: ModelConfig, x, *,
     with jax.named_scope("dispatch"):
         eg = constrain(experts.reshape(G, ng * k), ("b", None))
         wg = weights.reshape(G, ng * k)
-        onehot = constrain(jax.nn.one_hot(eg, E, dtype=jnp.int32),
+        if El != E:     # expert ids of the held block; others one-hot to 0
+            eg = eg - cfg.experts_offset
+        onehot = constrain(jax.nn.one_hot(eg, El, dtype=jnp.int32),
                            ("b", None, None))
         slot = (jnp.cumsum(onehot, axis=1) - 1) * onehot          # per-group
         flat_slot = slot.sum(-1)
         keep = flat_slot < cap
-        dest = jnp.where(keep, eg * cap + flat_slot, E * cap)     # (G, ng*k)
+        if El != E:
+            held = (eg >= 0) & (eg < El)
+            keep = keep & held
+        dest = jnp.where(keep, eg * cap + flat_slot, El * cap)    # (G, ng*k)
         aux = {"balance": balance,
-               "dropped": jnp.sum(~keep).astype(jnp.float32),
-               "routed": jnp.asarray(keep.size, jnp.float32)}
+               "dropped": jnp.sum(~keep if El == E else held & ~keep
+                                  ).astype(jnp.float32),
+               "routed": (jnp.asarray(keep.size, jnp.float32) if El == E
+                          else jnp.sum(held).astype(jnp.float32))}
         slot_choice = jax.vmap(
-            lambda ds: _invert_slots(ds, E * cap))(dest)         # (G, E*cap)
+            lambda ds: _invert_slots(ds, El * cap))(dest)        # (G, El*cap)
 
         xg = constrain(xf.reshape(G, ng, d), ("b", None, None))
         buf = jax.vmap(lambda xs, ds, sc: _dispatch(xs, ds, sc, k))(
             xg, dest, slot_choice)                                # (G, E*cap, d)
-        buf = constrain(buf.reshape(G, E, cap, d), ("b", "m", None, None))
+        buf = constrain(buf.reshape(G, El, cap, d), ("b", "m", None, None))
 
     with jax.named_scope("experts"):
         if ep_axis is not None:
@@ -259,7 +289,7 @@ def moe_ffn(params, cfg: ModelConfig, x, *,
             out_buf = constrain(jnp.einsum("gecf,efd->gecd", h_mid,
                                            params["wo"]),
                                 ("b", "m", None, None))
-            out_flat = constrain(out_buf.reshape(G, E * cap, d),
+            out_flat = constrain(out_buf.reshape(G, El * cap, d),
                                  ("b", None, None))
 
     with jax.named_scope("combine"):
@@ -280,6 +310,11 @@ def moe_decode_ffn(params, cfg: ModelConfig, x) -> jnp.ndarray:
     B, _, d = x.shape
     xf = x.reshape(B, d)
     weights, experts, _ = _route(cfg, xf @ params["router"])       # (B,k)
+    if cfg.experts_held:   # choices of experts held elsewhere add nothing
+        experts = experts - cfg.experts_offset
+        held = (experts >= 0) & (experts < cfg.experts_held)
+        weights = jnp.where(held, weights, 0.0)
+        experts = jnp.clip(experts, 0, cfg.experts_held - 1)
     wg = params["wi_gate"][experts]                                # (B,k,d,ff)
     wu = params["wi_up"][experts]
     wo = params["wo"][experts]                                     # (B,k,ff,d)

@@ -1,4 +1,4 @@
-"""Decoder stack: config-driven blocks (attention / MLA / Mamba / xLSTM ×
+"""Decoder stack: config-driven blocks (attention / MLA / Mamba / KDA / xLSTM ×
 dense / MoE FFN), lowered as ``lax.scan`` over repeating layer periods so HLO
 size stays O(period) instead of O(num_layers).
 
@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import LayerSpec, ModelConfig, Segment
 from repro.models import attention as attn
+from repro.models import kda as kda_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models import xlstm as xlstm_mod
@@ -44,6 +45,8 @@ def block_desc(cfg: ModelConfig, spec: LayerSpec) -> Dict[str, Any]:
         desc["mixer"] = attn.mla_desc(cfg)
     elif spec.mixer == "mamba":
         desc["mixer"] = ssm_mod.mamba_desc(cfg)
+    elif spec.mixer == "kda":
+        desc["mixer"] = kda_mod.kda_desc(cfg)
     else:
         raise ValueError(spec.mixer)
     if spec.ffn != "none":
@@ -84,6 +87,8 @@ def block_train(params, cfg: ModelConfig, spec: LayerSpec, x, positions,
                                         positions)
         elif spec.mixer == "mla":
             h = attn.mla_forward(params["mixer"], cfg, spec, h, positions)
+        elif spec.mixer == "kda":
+            h = kda_mod.kda_forward(params["mixer"], cfg, h)
         else:  # mamba
             h = ssm_mod.mamba_forward(params["mixer"], cfg, h)
     x = x + _boundary(h)
@@ -124,6 +129,8 @@ def block_prefill(params, cfg: ModelConfig, spec: LayerSpec, x, positions,
         h, cache = attn.attn_prefill(params["mixer"], cfg, spec, h, positions, max_len)
     elif spec.mixer == "mla":
         h, cache = attn.mla_prefill(params["mixer"], cfg, spec, h, positions, max_len)
+    elif spec.mixer == "kda":
+        h, cache = kda_mod.kda_forward(params["mixer"], cfg, h, return_state=True)
     else:
         h, cache = ssm_mod.mamba_forward(params["mixer"], cfg, h, return_state=True)
     x = x + h
@@ -145,6 +152,8 @@ def block_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_len: int,
         return attn.init_mla_cache(cfg, batch, max_len, dtype)
     if spec.mixer == "mamba":
         return ssm_mod.init_mamba_state(cfg, batch, dtype)
+    if spec.mixer == "kda":
+        return kda_mod.init_kda_state(cfg, batch, dtype)
     if spec.mixer == "mlstm":
         return xlstm_mod.init_mlstm_state(cfg, batch, dtype)
     if spec.mixer == "slstm":
@@ -169,6 +178,8 @@ def block_decode(params, cfg: ModelConfig, spec: LayerSpec, x, cache, pos,
     elif spec.mixer == "mla":
         h, new_cache = attn.mla_decode(params["mixer"], cfg, spec, h, cache, pos,
                                        absorb=mla_absorb)
+    elif spec.mixer == "kda":
+        h, new_cache = kda_mod.kda_decode(params["mixer"], cfg, h, cache)
     else:
         h, new_cache = ssm_mod.mamba_decode(params["mixer"], cfg, h, cache)
     x = x + h

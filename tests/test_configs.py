@@ -6,7 +6,7 @@ from repro.configs import (ALL_ARCHS, SHAPES, applicable_shapes, get_config,
 
 
 def test_all_archs_resolve():
-    assert len(ALL_ARCHS) == 10
+    assert len(ALL_ARCHS) == 11
     for name in ALL_ARCHS:
         cfg = get_config(name)
         assert cfg.name == name

@@ -144,3 +144,72 @@ def test_moe_grad_program_scatters_no_rows(groups):
     row_gathers = [e for e in eqns if e.primitive.name == "gather"
                    and e.outvars[0].aval.shape[-1:] == (D,)]
     assert len(row_gathers) >= 4        # dispatch and combine, both ways
+
+
+def _share_cfg(**kw):
+    import dataclasses
+    return dataclasses.replace(_cfg(1.0), num_experts=32, top_k=4,
+                               router_act="sigmoid", routed_scale=2.446, **kw)
+
+
+def _held(params, off, n):
+    return dict(params, **{k: params[k][off:off + n]
+                           for k in ("wi_gate", "wi_up", "wo")})
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+def test_held_shares_add_up_to_the_whole_layer(groups):
+    """The 8-expert shares of all E/8 offsets, the shared expert (which
+    every chip computes alike) counted once, add up to the whole layer;
+    their routed and dropped choices add up to the whole layer's too."""
+    import dataclasses
+    cfg = _share_cfg()
+    E, held = cfg.num_experts, 8
+    k = jax.random.split(jax.random.PRNGKey(3), 7)
+    params = dict(_params(_cfg(1.0)), router=jax.random.normal(k[0], (D, E)),
+                  **{n: jax.random.normal(k[i + 1], s) * 0.3 for i, (n, s) in
+                     enumerate((("wi_gate", (E, D, 20)), ("wi_up", (E, D, 20)),
+                                ("wo", (E, 20, D))))})
+    x = _x()
+    whole, aux = moe.moe_ffn(params, cfg, x, groups=groups)
+    shared = mlp(params["shared"], x, cfg.activation)
+    total, routed, dropped = shared, 0.0, 0.0
+    for off in range(0, E, held):
+        c = dataclasses.replace(cfg, experts_held=held, experts_offset=off)
+        out, a = moe.moe_ffn(_held(params, off, held), c, x, groups=groups)
+        total = total + (out - shared)
+        routed += float(a["routed"])
+        dropped += float(a["dropped"])
+        np.testing.assert_allclose(float(a["balance"]), float(aux["balance"]),
+                                   rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               rtol=1e-4, atol=1e-5)
+    assert routed == float(aux["routed"]) == x.shape[0] * x.shape[1] * 4
+    assert dropped == float(aux["dropped"]) > 0
+
+
+def test_held_share_decode_matches_dispatch():
+    """The single-token decode path computes the same held share."""
+    import dataclasses
+    cfg = dataclasses.replace(_share_cfg(), experts_held=8, experts_offset=8,
+                              capacity_factor=64.0)
+    params = _held(dict(_params(_cfg(1.0)), router=jax.random.normal(
+        jax.random.PRNGKey(4), (D, 32)), **{n: jnp.tile(v, (4,) + (1,) * (
+            v.ndim - 1)) for n, v in _params(_cfg(1.0)).items()
+            if n in ("wi_gate", "wi_up", "wo")}), 8, 8)
+    x = _x()[:, :1]
+    want, _ = moe.moe_ffn(params, cfg, x, groups=1)
+    got = moe.moe_decode_ffn(params, cfg, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_sigmoid_router_weights_sum_to_routed_scale():
+    cfg = _share_cfg()
+    logits = jax.random.normal(jax.random.PRNGKey(5), (64, cfg.num_experts))
+    w, ex, bal = moe._route(cfg, logits)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 2.446, rtol=1e-6)
+    # the top 4 by sigmoid score, in score order
+    np.testing.assert_array_equal(np.asarray(ex),
+                                  np.argsort(-np.asarray(logits), -1)[:, :4])
+    assert 0.0 < float(bal) and bool(jnp.isfinite(bal))
