@@ -72,7 +72,8 @@ from repro.launch.steps import (_make_synced_train_step, _world_of,
                                 make_local_train_step, make_param_round_step,
                                 make_pipeline_train_step,
                                 make_sharded_train_step, make_train_step,
-                                merge_opt_rows, worker_view)
+                                merge_opt_rows, synced_step_compiler_options,
+                                worker_view)
 from repro.models import Model
 from repro.models.sharding_ctx import set_mesh_ctx
 from repro.optim import make_optimizer, make_sharded_optimizer, warmup_cosine
@@ -756,7 +757,10 @@ class TrainSession:
             step_fn, _, init_sync_state = _make_synced_train_step(
                 self.model, self.optimizer, engine, self.mesh, self.axes,
                 per_worker_params=sched.diverges_params)
-            self._sync = jax.jit(step_fn, donate_argnums=(0, 1, 2))
+            self._sync = jax.jit(
+                step_fn, donate_argnums=(0, 1, 2),
+                compiler_options=synced_step_compiler_options(engine,
+                                                              self.mesh))
             self._sync_state = init_sync_state(self._params)
         if "local" in sched.computes:
             self._local = jax.jit(

@@ -34,6 +34,8 @@ _OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _CONST_RE = re.compile(r"s32\[\]\s+constant\((\d+)\)")
 _GROUPS_RE = re.compile(r"replica_groups=\{\{([^}]*)\}")
 _IOTA_GROUPS_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
+_LAYOUT_RE = re.compile(r"\{[^{}]*\}")
+_OPCODE_RE = re.compile(r"\s([a-z][\w\-]*)\(")
 
 COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                   "collective-permute")
@@ -97,11 +99,10 @@ def parse_hlo(text: str) -> Tuple[Dict[str, Computation], str]:
         if not md:
             continue
         name, rhs = md.group(1), md.group(2)
-        op = ""
-        # op token: word before '(' after shape spec
-        mo = re.search(r"\}?\s*([\w\-]+)\(", rhs)
-        if mo:
-            op = mo.group(1)
+        # the opcode: the first word before '(' once the layouts, whose
+        # tiles read like calls ("{1,0:T(8,128)}"), are taken out
+        mo = _OPCODE_RE.search(" " + _LAYOUT_RE.sub("", rhs))
+        op = mo.group(1) if mo else ""
         cur.defs[name] = rhs
         cur.instructions.append(Instruction(name, rhs, op))
     return comps, entry
@@ -239,3 +240,88 @@ def analyze(text: str, total_devices: int = 1) -> HLOStats:
     walk(entry, 1.0, ())
     stats.while_trip_counts = seen_while
     return stats
+
+
+# ---------------------------------------------------------------------------
+# Overlap of a scheduled program's collectives with its compute
+# ---------------------------------------------------------------------------
+
+_COMPUTE_OPS = ("fusion", "convolution", "custom-call")
+_CHAIN_RE = re.compile(r'chain_id="([^"]*)"')
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_FUSION_CALL_RE = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def _is_collective(op: str) -> bool:
+    return op.startswith(COLLECTIVE_OPS)
+
+
+def exchange_overlap(text: str, scope: str = "grad_sync") -> Optional[float]:
+    """Share of the bytes of the collectives under the named scope ``scope``
+    that a scheduled HLO module (``compiled.as_text()``) overlaps with
+    compute; None when no collective runs under that scope.
+
+    A collective is asynchronous when its exchange spans several
+    instructions of one schedule (an entry or loop body): an
+    ``all-reduce-start`` and the ``all-reduce-done`` that consumes it, or the
+    TPU's async collective fusions, whose start, compute and done fusions
+    each hold the same collective under one ``chain_id``.  It overlaps when
+    at least one compute instruction (a fusion, convolution or custom call)
+    is scheduled between its first and last instruction.  A synchronous
+    collective overlaps nothing.  Bytes are the collective's operand bytes,
+    each instruction of the text counted once."""
+    comps, _ = parse_hlo(text)
+    fused = {m.group(1) for c in comps.values() for ins in c.instructions
+             if ins.op == "fusion"
+             for m in [_FUSION_CALL_RE.search(ins.line)] if m}
+    held: Dict[str, List[Tuple[Computation, Instruction]]] = {}
+
+    def holds(name: str) -> List[Tuple[Computation, Instruction]]:
+        """The collectives of a fused computation, nested fusions too."""
+        if name not in held:
+            held[name] = []
+            comp = comps.get(name)
+            for ins in comp.instructions if comp else ():
+                if _is_collective(ins.op):
+                    held[name].append((comp, ins))
+                elif ins.op == "fusion":
+                    m = _FUSION_CALL_RE.search(ins.line)
+                    held[name] += holds(m.group(1)) if m else []
+        return held[name]
+
+    total = overlapped = 0.0
+    for cname, comp in comps.items():
+        if cname in fused:
+            continue
+        spans: Dict[str, List[int]] = {}
+        sizes: Dict[str, int] = {}
+        for i, ins in enumerate(comp.instructions):
+            if _is_collective(ins.op):
+                members = [(comp, ins)]
+            elif ins.op == "fusion":
+                m = _FUSION_CALL_RE.search(ins.line)
+                members = holds(m.group(1)) if m else []
+            else:
+                continue
+            for owner, c in members:
+                op_name = _OP_NAME_RE.search(c.line)
+                if not op_name or scope not in op_name.group(1):
+                    continue
+                chain = _CHAIN_RE.search(c.line)
+                if chain:
+                    key = "chain " + chain.group(1)
+                elif c.op.endswith("-done"):
+                    ops = _OPERAND_RE.findall(c.line.split(c.op + "(", 1)[1])
+                    key = ops[0] if ops else c.name
+                else:
+                    key = c.name
+                spans.setdefault(key, []).append(i)
+                if key not in sizes and not c.op.endswith("-done"):
+                    sizes[key] = _collective_bytes(owner, c, 1)[0]
+        for key, at in spans.items():
+            nbytes = sizes.get(key, 0)
+            total += nbytes
+            if any(comp.instructions[j].op in _COMPUTE_OPS
+                   for j in range(min(at) + 1, max(at))):
+                overlapped += nbytes
+    return overlapped / total if total else None
